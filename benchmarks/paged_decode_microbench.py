@@ -40,9 +40,10 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config
-    from repro.kernels.paged_attention import paged_attention
+    from repro.kernels.ops import paged_attention
+    from repro.kernels.paged_attention import kv_pack
     from repro.models import lm
-    from repro.serve.kvcache import gather_read_attention
+    from repro.serve.kvcache import gather_read_attention, init_kv_pool
 
     B, H, KV, hd = 8, 8, 4, 64
     bs = 16
@@ -53,7 +54,8 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
 
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     q = jax.random.normal(ks[0], (B, H, hd))
-    pool_kv = jax.random.normal(ks[1], (2, N, KV, bs, hd))
+    P = kv_pack(KV, hd)
+    pool_kv = jax.random.normal(ks[1], (N, 1, 2, KV // P, bs, P * hd))
     tables = jnp.asarray(
         1 + np.arange(B * mb, dtype=np.int32).reshape(B, mb))
 
@@ -64,10 +66,10 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
     for occ_name, pos in occupancies:
         lengths = jnp.full((B,), pos, jnp.int32)
         t_paged = _time_ms(
-            lambda: paged_attention(q, pool_kv, tables, lengths,
+            lambda: paged_attention(q, pool_kv, tables, lengths, 0,
                                     impl="xla"), iters)
         t_gather = _time_ms(
-            lambda: gather_read(q, pool_kv, tables, lengths), iters)
+            lambda: gather_read(q, pool_kv, 0, tables, lengths), iters)
         yield (f"paged_read_{occ_name}_occ_ms", f"{t_paged:.3f}",
                f"{t_gather/t_paged:.2f}x_gather")
         yield (f"gather_read_{occ_name}_occ_ms", f"{t_gather:.3f}", "")
@@ -75,7 +77,7 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
     # Pallas interpreter datapoint (trajectory record; see module docstring)
     lengths = jnp.full((B,), bs - 1, jnp.int32)
     t_pallas = _time_ms(
-        lambda: paged_attention(q, pool_kv, tables, lengths,
+        lambda: paged_attention(q, pool_kv, tables, lengths, 0,
                                 impl="pallas"), max(2, iters // 10))
     yield ("pallas_interpret_read_low_occ_ms", f"{t_pallas:.3f}",
            "interpret_mode")
@@ -86,8 +88,7 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
     mb2 = 32                            # large pool: the short rows below
     #                                     sit at ~3% of per-row capacity
     N2 = B * mb2 + 1
-    pool = jnp.zeros((cfg.num_layers, 2, N2, cfg.num_kv_heads, bs, cfg.hd),
-                     jnp.bfloat16)
+    pool = init_kv_pool(cfg, N2, bs)
     tables2 = jnp.asarray(
         1 + np.arange(B * mb2, dtype=np.int32).reshape(B, mb2))
     lengths2 = jnp.full((B,), bs - 1, jnp.int32)

@@ -26,9 +26,9 @@ serve_mesh — tensor-parallel sharded serving: --mesh-model N KV-head-
           sharded engine vs single-device over one trace (bit-exact
           parity asserted; per-device pool bytes ~1/N; admission
           capacity at fixed device memory); honors --quick. Needs N
-          devices — when only serve_mesh is selected the harness forces
-          CPU host devices itself, otherwise set XLA_FLAGS=
-          --xla_force_host_platform_device_count=N up front
+          devices and fails with fewer — when only serve_mesh is
+          selected the harness forces CPU host devices itself, otherwise
+          set XLA_FLAGS=--xla_force_host_platform_device_count=N up front
 paged_decode — gather-free paged decode read path vs the gather oracle
           across pool occupancies; honors --quick
 decode_overlap — async decode lookahead vs the synchronous decode loop:
@@ -134,6 +134,9 @@ def main() -> None:
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.mesh_model}").strip()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (decode_overlap_microbench, fig9_micro_random_dag,
                    fig11_corun_throughput, fig13_lsdnn,
                    fig17_conditional_memory, fig21_incremental_timing,
@@ -148,13 +151,10 @@ def main() -> None:
     def _serve_mesh_rows():
         import jax
         if jax.device_count() < args.mesh_model:
-            # a 1-device default run stays green; the CI mesh leg sets
-            # XLA_FLAGS at the job level so the suite actually runs there
-            print(f"# serve_mesh: skipped — {jax.device_count()} "
-                  f"device(s) < mesh_model={args.mesh_model} (set "
-                  "XLA_FLAGS=--xla_force_host_platform_device_count="
-                  f"{args.mesh_model})", flush=True)
-            return iter(())
+            raise RuntimeError(
+                f"serve_mesh needs {args.mesh_model} devices, found "
+                f"{jax.device_count()} (on the CPU set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={args.mesh_model})")
         return serve_continuous.bench_mesh(quick=args.quick,
                                            mesh_model=args.mesh_model)
 

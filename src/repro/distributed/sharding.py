@@ -288,7 +288,8 @@ def serve_param_shardings(cfg: Any, params: Any, ctx: ShardCtx) -> Any:
 
 
 def serve_pool_spec(cfg: Any, ctx: ShardCtx) -> P:
-    """Spec for the stacked paged pool (L, 2, N, KV, bs, hd): KV sharded."""
+    """Spec for the paged pool (N, L, 2, R, bs, W): packed KV-head rows
+    sharded (``init_kv_pool`` packs per shard, so rows never straddle)."""
     if serve_attn_sharded(cfg, serve_tp_size(ctx)):
         return P(None, None, None, ctx.tp, None, None)
     return P(None, None, None, None, None, None)
@@ -319,17 +320,15 @@ def gather_tp(x: Any, axis: int = -1) -> Any:
 def manual_serve_map(fn, ctx: ShardCtx, in_specs, out_specs):
     """shard_map ``fn`` over ``ctx.mesh`` with the manual ShardCtx active.
 
-    ``check_rep=False`` because replicated outputs (sampled tokens, carry)
+    ``check_vma=False`` because replicated outputs (sampled tokens, carry)
     are produced by identical per-shard compute on gathered — bitwise
-    identical — operands, which the replication checker cannot see.
+    identical — operands, which the varying-axes checker cannot see.
     """
-    from jax.experimental.shard_map import shard_map
-
     mctx = dataclasses.replace(ctx, manual=True)
 
     def body(*args):
         with use_shard_ctx(mctx):
             return fn(*args)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -88,7 +88,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "prune", "interpret"))
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
-                    prune: bool = True, interpret: bool = True):
+                    prune: bool = True, *, interpret: bool):
     """q: (B,S,H,hd); k,v: (B,T,KV,hd) -> (B,S,H,hd).
 
     prune=True (causal only) skips kv blocks fully above the diagonal —

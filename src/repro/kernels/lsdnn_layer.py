@@ -43,7 +43,7 @@ def _lsdnn_kernel(y_ref, w_ref, b_ref, o_ref, acc_ref, *, cap: float):
                                              "block_k", "interpret"))
 def lsdnn_layer(y, w, b, cap: float = 32.0, block_m: int = 256,
                 block_n: int = 256, block_k: int = 512,
-                interpret: bool = True):
+                *, interpret: bool):
     """y: (T, F); w: (F, G); b: (G,) -> clamp(relu(y @ w + b), 0, cap)."""
     T, F = y.shape
     G = w.shape[1]

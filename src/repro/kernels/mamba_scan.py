@@ -57,7 +57,7 @@ def _scan_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, hT_ref, h_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("block_d", "chunk", "interpret"))
 def mamba_scan(dt, x, Bc, Cc, A, block_d: int = 512, chunk: int = 128,
-               interpret: bool = True):
+               *, interpret: bool):
     """Returns (y (B,S,dI) fp32, hT (B,dI,N) fp32)."""
     B, S, dI = x.shape
     N = A.shape[1]

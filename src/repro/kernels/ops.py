@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Selects the execution mode per backend: Mosaic lowering on TPU,
-interpreter on CPU (correctness validation — this container is CPU-only;
-TPU v5e is the target, DESIGN.md §2.3).
+The one place that derives a kernel's execution mode from the platform:
+Mosaic lowering on TPU, the Pallas interpreter (correctness validation)
+everywhere else. The kernel entry points themselves take ``interpret`` as
+a required keyword, so no caller can fall into the interpreter on the chip
+by omission.
 
 :func:`default_paged_impl` resolves which paged decode-attention read path
 the serve engine uses (see ``paged_attention.py``): the ``REPRO_PAGED_IMPL``
@@ -49,8 +51,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                   prune=prune, interpret=not on_tpu())
 
 
-def paged_attention(q, pool_kv, tables, lengths, impl: str = "pallas"):
-    return _paged(q, pool_kv, tables, lengths, impl=impl,
+def paged_attention(q, pool_kv, tables, lengths, layer, impl: str = "pallas"):
+    return _paged(q, pool_kv, tables, lengths, layer, impl=impl,
                   interpret=not on_tpu())
 
 

@@ -21,13 +21,8 @@ __all__ = ["make_production_mesh", "make_ctx", "small_mesh"]
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions (axis_types landed after 0.4.x)."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

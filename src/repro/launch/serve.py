@@ -34,6 +34,7 @@ checkpoint-preempted), then a snapshot + journal flush, then close:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import signal
 import threading
@@ -47,6 +48,7 @@ from ..distributed.sharding import validate_serve_mesh
 from ..models import lm
 from ..obs import Observability, StatsLogger
 from ..serve.engine import SNAPSHOT_FILE, ServeEngine
+from .compile_cache import enable_compile_cache
 from .mesh import make_ctx, small_mesh
 
 
@@ -55,7 +57,9 @@ def main() -> None:
     ap.add_argument("--arch", default="stablelm-1.6b",
                     help="model architecture (default: the quick smoke "
                          "workload's stablelm-1.6b)")
-    ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--preset", default="smoke", choices=["smoke", "full"],
+                    help="smoke: the reduced CPU-sized config; full: the "
+                         "published widths with bf16 weights, TPU only")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=32)
@@ -132,10 +136,20 @@ def main() -> None:
                          "chrome://tracing) of the run (implies "
                          "observability on)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "smoke":
         cfg = cfg.smoke()
+    else:
+        # published widths are served from bf16 weights (training keeps its
+        # f32 masters) and only on the chip: a JAX that did not find the
+        # TPU would otherwise carry on, slowly, on the CPU
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            raise SystemExit(f"--preset full needs a TPU; JAX found "
+                             f"{platform!r}")
+        cfg = dataclasses.replace(cfg, param_dtype="bfloat16")
     if cfg.frontend != "none":
         print(f"note: {cfg.name} uses a stub frontend; serving the text "
               "backbone only")
@@ -177,6 +191,10 @@ def main() -> None:
                      watchdog_s=args.watchdog,
                      fault_inject=args.fault_inject,
                      obs=obs) as eng:
+        if args.preset == "full" and eng.paged and eng.paged_impl != "pallas":
+            raise SystemExit(f"--preset full serves through the Pallas "
+                             f"paged kernel, not {eng.paged_impl!r} (unset "
+                             f"REPRO_PAGED_IMPL)")
         replayed = {}
         if args.state_dir:
             # crash/restart recovery: warm-start from a prior snapshot

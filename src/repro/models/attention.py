@@ -178,14 +178,17 @@ def attention(p, x, cfg: ModelConfig, positions,
     return y
 
 
-def paged_decode_attention(p, x, cfg: ModelConfig, pool_kv, tables,
+def paged_decode_attention(p, x, cfg: ModelConfig, pool_kv, layer, tables,
                            pos, active, impl: Optional[str] = None):
     """One-token decode against ONE layer's paged KV pool (the
     paged-attention read path of the continuous-batching engine; the
     contiguous :func:`decode_attention` stays as the reference).
 
-    x: (B, 1, D); pool_kv: (2, N, KV, block, hd) — this layer's stacked K/V
-    pages (the new token's K and V land in one fused scatter); tables:
+    x: (B, 1, D); pool_kv: (N, L, 2, R, block, W) — the whole paged K/V
+    pool (:func:`repro.serve.kvcache.init_kv_pool`); layer: int32 scalar,
+    this layer's index into it (the new token's K and V land in one fused
+    in-place write, and the read addresses the layer itself, so no
+    per-layer slice is copied); tables:
     (B, max_blocks) int32 block tables (tail entries point at the sink
     block); pos: (B,) int32 PER-ROW positions — rows of a continuously
     batched decode sit at different sequence lengths, which is exactly what
@@ -216,13 +219,14 @@ def paged_decode_attention(p, x, cfg: ModelConfig, pool_kv, tables,
     H = p["wq"].shape[-1] // hd        # local head count under serve TP
     cdt = dtype_of(cfg.compute_dtype)
     q, k, v = _project_qkv(p, x, cfg, pos[:, None])
-    pool_kv = append_kv(pool_kv, k[:, 0], v[:, 0], tables, pos, active)
+    pool_kv = append_kv(pool_kv, layer, k[:, 0], v[:, 0], tables, pos,
+                        active)
     if impl == "gather":
-        out = gather_read_attention(q.reshape(B, H, hd), pool_kv, tables,
-                                    pos)
+        out = gather_read_attention(q.reshape(B, H, hd), pool_kv, layer,
+                                    tables, pos)
     else:
         out = paged_attention(q.reshape(B, H, hd), pool_kv, tables, pos,
-                              impl=impl)
+                              layer, impl=impl)
     out2 = out.reshape(B, H * hd).astype(cdt)
     if out2.shape[-1] != p["wo"].shape[0]:   # serve TP: concat local heads
         out2 = gather_tp(out2, -1)
@@ -232,8 +236,8 @@ def paged_decode_attention(p, x, cfg: ModelConfig, pool_kv, tables,
     return y[:, None, :], pool_kv
 
 
-def paged_prefill_window_attention(p, x, cfg: ModelConfig, pool_kv, tables,
-                                   positions, valid):
+def paged_prefill_window_attention(p, x, cfg: ModelConfig, pool_kv, layer,
+                                   tables, positions, valid):
     """One chunked-prefill WINDOW against one layer's paged KV pool.
 
     The two-phase-admission engine feeds a prompt into the pool in
@@ -245,11 +249,12 @@ def paged_prefill_window_attention(p, x, cfg: ModelConfig, pool_kv, tables,
     materializing read is fine here; the per-token decode hot path stays on
     the gather-free kernels.
 
-    x: (B, C, D) window hidden states; pool_kv: (2, N, KV, block, hd) this
-    layer's pages; tables: (B, max_blocks) int32; positions: (B, C) int32
-    absolute positions ``start[b] + c``; valid: (B, C) bool — False entries
-    (rows not prefilling, window tail past the prompt) scatter to the sink
-    block and their outputs are junk the engine never reads. Valid entries
+    x: (B, C, D) window hidden states; pool_kv: (N, L, 2, R, block, W)
+    the whole pool, written in place at ``layer`` (int32 scalar); tables:
+    (B, max_blocks) int32; positions: (B, C) int32 absolute positions
+    ``start[b] + c``; valid: (B, C) bool — False entries (rows not
+    prefilling, window tail past the prompt) are not written and their
+    outputs are junk the engine never reads. Valid entries
     always form a per-row prefix, so a valid query's causal span
     ``kpos <= positions[b, c]`` is fully populated. Returns (y (B, C, D),
     pool_kv).
@@ -263,9 +268,9 @@ def paged_prefill_window_attention(p, x, cfg: ModelConfig, pool_kv, tables,
     G = H // KV
     cdt = dtype_of(cfg.compute_dtype)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    pool_kv = scatter_token_window(pool_kv, k, v, tables, positions[:, 0],
-                                   valid)
-    ks, vs = gather_pages(pool_kv, tables)           # (B, KV, T, hd)
+    pool_kv = scatter_token_window(pool_kv, layer, k, v, tables,
+                                   positions[:, 0], valid)
+    ks, vs = gather_pages(pool_kv, layer, tables, hd)  # (B, KV, T, hd)
     T = ks.shape[2]
     qg = q.reshape(B, C, KV, G, hd)
     s = jnp.einsum("bckgh,bksh->bkgcs", qg, ks,

@@ -480,7 +480,7 @@ def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
     reference implementation (the two agree token-for-token under greedy
     decoding, see ``tests/test_serve_continuous.py``).
 
-    pool_kv: (L, 2, N, KV, block, hd) stacked K/V pages; tables:
+    pool_kv: (N, L, 2, R, block, W) paged K/V pool; tables:
     (B, max_blocks) int32; lengths: (B,) int32; token: (B,) int32; active:
     (B,) bool (inactive rows write KV to the sink block and their logits
     are discarded). ``impl`` (trace-static) picks the attention read path —
@@ -502,15 +502,24 @@ def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
         x1 = x1 + sinusoidal_positions(pos, cfg.d_model).astype(cdt)
 
     def paged_attn(lp, h1, layer_cache):
-        return paged_decode_attention(lp, h1, cfg, layer_cache,
-                                      tables, pos, active, impl=impl)
+        pkv, li = layer_cache
+        y, pkv = paged_decode_attention(lp, h1, cfg, pkv, li, tables, pos,
+                                        active, impl=impl)
+        return y, (pkv, li)
 
+    # the pool rides the layer loop as CARRY and each layer updates its
+    # pages in place: as scan xs/ys every step would write a new stacked
+    # pool (a pool-sized temporary)
     def layer(c, l_xs):
-        lp, pkv = l_xs
-        c, pkv = _block_decode(lp, c, cfg, pkv, pos, attn_fn=paged_attn)
-        return c, pkv
+        x, pkv = c
+        lp, li = l_xs
+        x, (pkv, _) = _block_decode(lp, x, cfg, (pkv, li), pos,
+                                    attn_fn=paged_attn)
+        return (x, pkv), None
 
-    x1, pool_kv = jax.lax.scan(layer, x1, (params["blocks"], pool_kv))
+    (x1, pool_kv), _ = jax.lax.scan(
+        layer, (x1, pool_kv),
+        (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     x1 = rms_norm(x1, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bd,dv->bv", x1, head.astype(cdt),
@@ -620,13 +629,14 @@ def decode_chunk_slots(cfg: ModelConfig, params, state, carry, n: int,
     return _decode_chunk_scan(step, state, carry, n)
 
 
-def _block_window(p, x, cfg: ModelConfig, attn_fn, pkv_l):
+def _block_window(p, x, cfg: ModelConfig, attn_fn, layer_cache):
     """One layer over a chunked-prefill window. Mirrors :func:`_block_apply`
     with the attention swapped for a paged read/write through ``attn_fn(p,
-    h, pkv_l) -> (y, pkv_l)`` — ln1/residual/ln2/MoE-or-MLP stay shared so
-    the window path cannot structurally diverge from full prefill."""
+    h, layer_cache) -> (y, layer_cache)`` — ln1/residual/ln2/MoE-or-MLP stay
+    shared so the window path cannot structurally diverge from full
+    prefill."""
     h = rms_norm(x, p["ln1"], cfg.rms_eps)
-    y, pkv_l = attn_fn(p, h, pkv_l)
+    y, layer_cache = attn_fn(p, h, layer_cache)
     x = x + y
     h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
     if cfg.moe:
@@ -634,7 +644,7 @@ def _block_window(p, x, cfg: ModelConfig, attn_fn, pkv_l):
         x = x + y2
     else:
         x = x + mlp(p, h2, cfg)
-    return x, pkv_l
+    return x, layer_cache
 
 
 def prefill_window_paged(cfg: ModelConfig, params, pool_kv, tables, tokens,
@@ -651,7 +661,7 @@ def prefill_window_paged(cfg: ModelConfig, params, pool_kv, tables, tokens,
     depends on prompt lengths, mixed-length admission groups share one
     compiled program.
 
-    pool_kv: (L, 2, N, KV, block, hd); tables: (B, max_blocks) int32;
+    pool_kv: (N, L, 2, R, block, W); tables: (B, max_blocks) int32;
     tokens: (B, C) int32 window tokens (invalid entries arbitrary); start:
     (B,) int32 per-row window origin (absolute position of window column 0);
     valid: (B, C) bool (False for rows not prefilling and past-prompt
@@ -684,16 +694,22 @@ def prefill_window_paged(cfg: ModelConfig, params, pool_kv, tables, tokens,
     if cfg.pos_emb == "sinusoidal":
         x = x + sinusoidal_positions(positions, cfg.d_model).astype(cdt)
 
-    def win_attn(lp, h, pkv_l):
-        return paged_prefill_window_attention(lp, h, cfg, pkv_l, tables,
-                                              positions, valid)
+    def win_attn(lp, h, layer_cache):
+        pkv, li = layer_cache
+        y, pkv = paged_prefill_window_attention(lp, h, cfg, pkv, li, tables,
+                                                positions, valid)
+        return y, (pkv, li)
 
+    # pool as layer-loop carry, updated in place (see decode_step_paged)
     def layer(c, l_xs):
-        lp, pkv_l = l_xs
-        c, pkv_l = _block_window(lp, c, cfg, win_attn, pkv_l)
-        return c, pkv_l
+        xx, pkv = c
+        lp, li = l_xs
+        xx, (pkv, _) = _block_window(lp, xx, cfg, win_attn, (pkv, li))
+        return (xx, pkv), None
 
-    x, pool_kv = jax.lax.scan(layer, x, (params["blocks"], pool_kv))
+    (x, pool_kv), _ = jax.lax.scan(
+        layer, (x, pool_kv),
+        (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     x_last = x[jnp.arange(B), last_idx]
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -488,7 +488,8 @@ class ServeEngine:
         if self.paged:
             self._pool = BlockPool(kv_blocks, block_size)
             self._pkv = self._place_pool(
-                init_kv_pool(cfg, kv_blocks, block_size))
+                init_kv_pool(cfg, kv_blocks, block_size,
+                             tp=self._tp if self._pool_sharded else 1))
             if self.prefix_cache:
                 self._prefix = PrefixCache(self._pool)
             if self._pool_sharded:
@@ -985,12 +986,11 @@ class ServeEngine:
                 [len(t) for t in ptoks], np.int32)
             ids = [n["block"] for n in nodes]
             hp = np.asarray(jax.device_get(self._pkv))
-            # (L, 2, N, KV, bs, hd): page i on axis 2 is node i's block.
+            # (N, L, 2, R, bs, W): page i on axis 0 is node i's block.
             # Stored as RAW BYTES (uint8 view): npz round-trips bfloat16
             # only as opaque void, so the restore side re-views with the
             # live pool dtype (recorded below for the compat check)
-            pg = np.ascontiguousarray(hp[:, :, ids] if ids
-                                      else hp[:, :, :0])
+            pg = np.ascontiguousarray(hp[ids] if ids else hp[:0])
             arrays["prefix_pages"] = pg.view(np.uint8)
             meta["pool_dtype"] = str(hp.dtype)
         n = write_snapshot(path, meta, arrays)
@@ -1038,7 +1038,7 @@ class ServeEngine:
                     f"snapshot pool dtype mismatch: "
                     f"{meta.get('pool_dtype')!r} vs engine {hp.dtype}")
             pages = pages.view(hp.dtype)     # stored as raw uint8 bytes
-            want = hp.shape[:2] + (pages.shape[2],) + hp.shape[3:]
+            want = (pages.shape[0],) + hp.shape[1:]
             if int(meta.get("block_size", -1)) != self._pool.block_size \
                     or pages.shape != want:
                 raise SnapshotCorrupt(
@@ -1058,7 +1058,7 @@ class ServeEngine:
                     max(0, self._pool.num_free_unreserved - 1))
             ids = self._pool.alloc(n) if n > 0 else []
             if ids:
-                hp[:, :, ids] = pages[:, :, :len(ids)]
+                hp[ids] = pages[:len(ids)]
                 self._pkv = self._place_pool(jnp.asarray(hp))
                 created = self._prefix.import_nodes(entries[:len(ids)],
                                                     ids)
@@ -2291,7 +2291,8 @@ class ServeEngine:
         if self.paged:
             kv_blocks, block_size = self._kv_geom
             new_pool = BlockPool(kv_blocks, block_size)
-            new_pkv = init_kv_pool(self.cfg, kv_blocks, block_size)
+            new_pkv = init_kv_pool(self.cfg, kv_blocks, block_size,
+                                   tp=self._tp if self._pool_sharded else 1)
         else:
             new_state = {k: v
                          for k, v in lm.init_cache(self.cfg, B,

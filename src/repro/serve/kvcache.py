@@ -7,11 +7,12 @@ the pool, join and leave the running batch at chunk boundaries, and free
 their blocks the moment they retire, so KV memory is bounded by the pool
 size instead of ``max_batch * max_len``.
 
-K and V are STACKED along a leading axis of one pool array
-``(L, 2, N, KV, block, hd)`` rather than held as two tensors: the decode
-write path appends a token's K *and* V with a single scatter launch and the
-read paths fetch page pairs with a single gather (previously two separate
-``.at[].set`` / gather launches per layer per token).
+K and V are STACKED in one pool array ``(N, L, 2, R, block, W)`` (block id,
+layer, K/V, a page of packed head rows — see :func:`init_kv_pool`) rather
+than held as two tensors: the decode write path appends a token's K *and*
+V with a single scatter launch and the read paths fetch page pairs with a
+single gather (previously two separate ``.at[].set`` / gather launches per
+layer per token).
 
 Two halves, deliberately separated:
 
@@ -59,6 +60,7 @@ from typing import List, Optional, Sequence
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels.paged_attention import kv_pack, unpack_pages
 
 __all__ = ["BlockPool", "init_kv_pool", "scatter_prefill_row",
            "scatter_prefill_rows", "scatter_token_window", "gather_pages",
@@ -378,12 +380,22 @@ class BlockPool:
 
 
 # ---------------------------------------------------------------- device side
-def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int
-                 ) -> jnp.ndarray:
-    """Allocate the pooled KV storage: one ``(L, 2, num_blocks, KV, block,
-    hd)`` array — axis 1 stacks K (0) and V (1) so appends/gathers touch
-    both halves in a single launch. Same layout as the contiguous cache
-    with the sequence dim split into pages."""
+def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
+                 tp: int = 1) -> jnp.ndarray:
+    """Allocate the pooled KV storage: one ``(num_blocks, L, 2, R, block,
+    W)`` array — block id first, then layer, K (0) / V (1), and a page of
+    ``block`` tokens whose rows pack ``P`` KV heads side by side into
+    ``W = P * hd`` lanes (``R = KV / P`` rows; see
+    :mod:`repro.kernels.paged_attention`). ``tp`` is the tensor-parallel
+    degree the pool is sharded over (on the ``R`` axis): packing is chosen
+    from the per-shard KV-head count so no row straddles two shards.
+
+    Why this layout: a page tile ``(block, W)`` fills the chip's 128 lanes
+    (one head per row would pad ``hd=64`` to 128, doubling the pool's HBM
+    footprint and the bytes every decode step reads), and with the block
+    id leading, every write is an update of whole trailing slabs — so the
+    decode chunk and prefill window programs update the pool in place
+    without relayout copies."""
     if cfg.ssm or cfg.hybrid_attn_every:
         raise ValueError(
             f"{cfg.name}: paged KV applies to attention caches only "
@@ -392,16 +404,23 @@ def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int
     # gather/scatter helpers above, so a models import here would cycle)
     from ..models.layers import dtype_of
     cdt = dtype_of(cfg.compute_dtype)
-    shape = (cfg.num_layers, 2, num_blocks, cfg.num_kv_heads, block_size,
-             cfg.hd)
+    P = kv_pack(cfg.num_kv_heads // tp, cfg.hd)
+    shape = (num_blocks, cfg.num_layers, 2, cfg.num_kv_heads // P,
+             block_size, P * cfg.hd)
     return jnp.zeros(shape, cdt)
+
+
+def _pack(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """(..., KV, hd) per-head vectors -> (..., KV*hd/width, width) packed
+    page rows (heads are contiguous, so packing is a reshape)."""
+    return x.reshape(x.shape[:-2] + (-1, width))
 
 
 def scatter_prefill_row(pool: jnp.ndarray, blocks: jnp.ndarray,
                         krow: jnp.ndarray, vrow: jnp.ndarray) -> jnp.ndarray:
     """Write one prefilled sequence into its blocks.
 
-    pool: (L, 2, N, KV, bs, hd); blocks: (nb,) int32; krow/vrow:
+    pool: (N, L, 2, R, bs, W); blocks: (nb,) int32; krow/vrow:
     (L, KV, S, hd) with ``S <= nb * bs``. Returns the updated pool.
     Jit-safe: ``nb`` and ``S`` are static shapes.
     """
@@ -414,28 +433,29 @@ def scatter_prefill_rows(pool: jnp.ndarray, blocks: jnp.ndarray,
                          ) -> jnp.ndarray:
     """Write a whole admitted GROUP's prefilled K and V in one scatter.
 
-    pool: (L, 2, N, KV, bs, hd); blocks: (Bg, nb) int32 — every row uses the
+    pool: (N, L, 2, R, bs, W); blocks: (Bg, nb) int32 — every row uses the
     same block count (the group shares one prompt length, and ``nb`` covers
     the PROMPT footprint only, so the compiled shape keys on the admission
     bucket, not on per-request ``max_new``); krows/vrows: (L, Bg, KV, S, hd)
     with ``S <= nb * bs``. Rows own disjoint blocks, so the scatter indices
     never collide.
     """
-    L, _, _, KV, bs, hd = pool.shape
+    _, L, _, R, bs, W = pool.shape
     Bg, nb = blocks.shape
+    _, _, KV, S, hd = krows.shape
+    P = W // hd
     rows = jnp.stack([krows, vrows], axis=1)     # (L, 2, Bg, KV, S, hd)
-    S = rows.shape[4]
     pad = nb * bs - S
     if pad:
         rows = jnp.pad(rows, ((0, 0), (0, 0), (0, 0), (0, 0), (0, pad),
                               (0, 0)))
-    # (L, 2, Bg, KV, nb*bs, hd) -> (L, 2, Bg, nb, KV, bs, hd): page-major
-    paged = rows.reshape(L, 2, Bg, KV, nb, bs, hd).transpose(
-        0, 1, 2, 4, 3, 5, 6)
-    return pool.at[:, :, blocks].set(paged)
+    # (L, 2, Bg, R, P, nb, bs, hd) -> (Bg, nb, L, 2, R, bs, P, hd): pages
+    paged = rows.reshape(L, 2, Bg, R, P, nb, bs, hd).transpose(
+        2, 5, 0, 1, 3, 6, 4, 7).reshape(Bg, nb, L, 2, R, bs, W)
+    return pool.at[blocks].set(paged.astype(pool.dtype))
 
 
-def scatter_token_window(pool_l: jnp.ndarray, new_k: jnp.ndarray,
+def scatter_token_window(pool: jnp.ndarray, layer, new_k: jnp.ndarray,
                          new_v: jnp.ndarray, tables: jnp.ndarray,
                          start: jnp.ndarray, valid: jnp.ndarray
                          ) -> jnp.ndarray:
@@ -443,23 +463,37 @@ def scatter_token_window(pool_l: jnp.ndarray, new_k: jnp.ndarray,
     block tables — the chunked-prefill scatter (one launch per layer per
     window, however many rows are mid-prefill).
 
-    pool_l: (2, N, KV, bs, hd) one layer's stacked pages; new_k/new_v:
-    (B, C, KV, hd); tables: (B, max_blocks) int32; start: (B,) int32 first
-    write position per row (token ``c`` lands at ``start[b] + c``); valid:
-    (B, C) bool — invalid entries (rows not prefilling, window tail past the
-    prompt) are redirected to the sink block. Valid entries of different
-    rows go through disjoint blocks, so the scatter indices never collide.
+    pool: (N, L, 2, R, bs, W) the whole pool, updated in place at ``layer``
+    (int32 scalar); new_k/new_v: (B, C, KV, hd); tables: (B, max_blocks)
+    int32; start: (B,) int32 first write position per row (token ``c``
+    lands at ``start[b] + c``); valid: (B, C) bool — invalid entries (rows
+    not prefilling, window tail past the prompt) are not written.
+
+    Writes whole pages: each of the ``ceil(C/bs) + 1`` pages a row's window
+    can touch is read, merged with the window's tokens, and written back —
+    a page holding no valid token goes to the sink block. Valid tokens of
+    different rows live in disjoint blocks, so live writes never collide.
     """
-    _, _, _, bs, _ = pool_l.shape
+    _, _, _, R, bs, W = pool.shape
     B, mb = tables.shape
     C = new_k.shape[1]
-    pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]   # (B, C)
-    idx = jnp.clip(pos // bs, 0, mb - 1)
-    blk = jnp.where(valid, jnp.take_along_axis(tables, idx, axis=1),
-                    SINK_BLOCK)
-    off = jnp.where(valid, pos % bs, 0)
-    new = jnp.stack([new_k, new_v], axis=2)          # (B, C, 2, KV, hd)
-    return pool_l.at[:, blk, :, off].set(new.astype(pool_l.dtype))
+    nw = -(-C // bs) + 1                         # pages one window spans
+    col = start[:, None] // bs + jnp.arange(nw, dtype=jnp.int32)  # (B, nw)
+    p = col[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)    # positions
+    c = p - start[:, None, None]                 # window column per slot
+    cc = jnp.clip(c, 0, C - 1)
+    hit = (c >= 0) & (c < C) & jnp.take_along_axis(
+        valid, cc.reshape(B, -1), axis=1).reshape(B, nw, bs) \
+        & (col < mb)[:, :, None]                 # (B, nw, bs)
+    blk = jnp.where(jnp.any(hit, axis=-1), jnp.take_along_axis(
+        tables, jnp.clip(col, 0, mb - 1), axis=1), SINK_BLOCK)
+    new = _pack(jnp.stack([new_k, new_v], axis=2), W)    # (B, C, 2, R, W)
+    tok = jnp.take_along_axis(new, cc.reshape(B, -1, 1, 1, 1), axis=1)
+    tok = tok.reshape(B, nw, bs, 2, R, W).transpose(0, 1, 3, 4, 2, 5)
+    old = pool[blk, layer]                       # (B, nw, 2, R, bs, W)
+    pages = jnp.where(hit[:, :, None, None, :, None],
+                      tok.astype(pool.dtype), old)
+    return pool.at[blk, layer].set(pages)
 
 
 def extend_block_tables(tables: jnp.ndarray, rows: jnp.ndarray,
@@ -513,18 +547,19 @@ def copy_blocks(pool: jnp.ndarray, srcs: jnp.ndarray, dsts: jnp.ndarray
     engine clones the block and repoints the row's table at the clone, so
     co-holders keep reading the original bits.
 
-    pool: (L, 2, N, KV, bs, hd); srcs/dsts: (M,) int32. Call sites pad with
+    pool: (N, L, 2, R, bs, W); srcs/dsts: (M,) int32. Call sites pad with
     ``SINK_BLOCK -> SINK_BLOCK`` repeats (the sink's contents are garbage by
     contract, and a self-copy is idempotent) to keep compiled shapes fixed.
     """
-    return pool.at[:, :, dsts].set(pool[:, :, srcs])
+    return pool.at[dsts].set(pool[srcs])
 
 
-def gather_pages(pool_l: jnp.ndarray, tables: jnp.ndarray):
+def gather_pages(pool: jnp.ndarray, layer, tables: jnp.ndarray, hd: int):
     """Gather one layer's K and V pages for a batch of sequences.
 
-    pool_l: (2, N, KV, bs, hd); tables: (B, max_blocks) int32 (unused tail
-    entries point at the sink). Returns ``(ks, vs)``, each (B, KV,
+    pool: (N, L, 2, R, bs, W); layer: int32 scalar; tables: (B,
+    max_blocks) int32 (unused tail entries point at the sink); hd: head
+    dim (unpacks the ``W``-lane rows). Returns ``(ks, vs)``, each (B, KV,
     max_blocks * bs, hd) with token position ``j`` at gathered index ``j``
     — the contiguous view the reference attention path reads, masked by
     each row's length. This materializes O(max_blocks) per row regardless
@@ -532,32 +567,32 @@ def gather_pages(pool_l: jnp.ndarray, tables: jnp.ndarray):
     against, not the serve hot path.
     """
     B, mb = tables.shape
-    _, _, KV, bs, hd = pool_l.shape
-    pages = pool_l[:, tables]                    # (2, B, mb, KV, bs, hd)
-    pages = pages.transpose(0, 1, 3, 2, 4, 5).reshape(2, B, KV, mb * bs, hd)
+    bs = pool.shape[4]
+    pages = unpack_pages(pool[tables, layer], hd)  # (B, mb, 2, KV, bs, hd)
+    KV = pages.shape[3]
+    pages = pages.transpose(2, 0, 3, 1, 4, 5).reshape(2, B, KV, mb * bs, hd)
     return pages[0], pages[1]
 
 
-def gather_read_attention(q: jnp.ndarray, pool_l: jnp.ndarray,
+def gather_read_attention(q: jnp.ndarray, pool: jnp.ndarray, layer,
                           tables: jnp.ndarray, lengths: jnp.ndarray
                           ) -> jnp.ndarray:
     """The reference (oracle) paged read path: gather the fully padded
     span via :func:`gather_pages`, mask by each row's length, softmax.
 
-    q: (B, H, hd) current-token queries; pool_l: (2, N, KV, bs, hd);
-    tables: (B, max_blocks) int32; lengths: (B,) int32 per-row position
-    ``pos`` (key positions ``0..pos`` attend). Returns (B, H, hd) in the
-    pool dtype. O(max_blocks) per row regardless of true length — the
-    single definition the gather-free kernels are tested and benchmarked
-    against (``tests/test_paged_attention.py``,
+    q: (B, H, hd) current-token queries; pool: (N, L, 2, R, bs, W); layer:
+    int32 scalar; tables: (B, max_blocks) int32; lengths: (B,) int32
+    per-row position ``pos`` (key positions ``0..pos`` attend). Returns
+    (B, H, hd) in the pool dtype. O(max_blocks) per row regardless of true
+    length — the single definition the gather-free kernels are tested and
+    benchmarked against (``tests/test_paged_attention.py``,
     ``benchmarks/paged_decode_microbench.py``) and the ``impl="gather"``
     branch of :func:`repro.models.attention.paged_decode_attention`.
     """
     B, H, hd = q.shape
-    KV = pool_l.shape[2]
+    ks, vs = gather_pages(pool, layer, tables, hd)  # (B, KV, T, hd)
+    KV, T = ks.shape[1], ks.shape[2]
     G = H // KV
-    ks, vs = gather_pages(pool_l, tables)        # (B, KV, T, hd), T=mb*bs
-    T = ks.shape[2]
     qg = q.reshape(B, KV, G, hd)
     s = jnp.einsum("bkgh,bksh->bkgs", qg, ks,
                    preferred_element_type=jnp.float32) * (hd ** -0.5)
@@ -571,22 +606,28 @@ def gather_read_attention(q: jnp.ndarray, pool_l: jnp.ndarray,
     return out.reshape(B, H, hd)
 
 
-def append_kv(pool_l: jnp.ndarray, new_k: jnp.ndarray, new_v: jnp.ndarray,
-              tables: jnp.ndarray, pos: jnp.ndarray, active: jnp.ndarray
-              ) -> jnp.ndarray:
+def append_kv(pool: jnp.ndarray, layer, new_k: jnp.ndarray,
+              new_v: jnp.ndarray, tables: jnp.ndarray, pos: jnp.ndarray,
+              active: jnp.ndarray) -> jnp.ndarray:
     """Write one decode step's K AND V for every batch row through the
-    block table — one fused scatter launch.
+    block table, in place on the whole pool.
 
-    pool_l: (2, N, KV, bs, hd); new_k/new_v: (B, KV, hd); tables:
-    (B, max_blocks); pos: (B,) int32 write position per row; active: (B,)
-    bool. Inactive rows are redirected to the sink block so they cannot
-    touch live pages.
+    pool: (N, L, 2, R, bs, W); layer: int32 scalar; new_k/new_v: (B, KV,
+    hd); tables: (B, max_blocks); pos: (B,) int32 write position per row;
+    active: (B,) bool. Each row's current page is read, the token set at
+    its offset, and the page written back whole (see
+    :func:`scatter_token_window`). Inactive rows are redirected to the
+    sink block so they cannot touch live pages.
     """
-    _, _, _, bs, _ = pool_l.shape
+    bs, W = pool.shape[4], pool.shape[5]
     B, mb = tables.shape
     idx = jnp.clip(pos // bs, 0, mb - 1)
     blk = jnp.where(active, jnp.take_along_axis(
         tables, idx[:, None], axis=1)[:, 0], SINK_BLOCK)
     off = jnp.where(active, pos % bs, 0)
-    new = jnp.stack([new_k, new_v], axis=1)      # (B, 2, KV, hd)
-    return pool_l.at[:, blk, :, off].set(new.astype(pool_l.dtype))
+    new = _pack(jnp.stack([new_k, new_v], axis=1), W)   # (B, 2, R, W)
+    hit = jnp.arange(bs, dtype=jnp.int32)[None, :] == off[:, None]
+    pages = jnp.where(hit[:, None, None, :, None],
+                      new[:, :, :, None, :].astype(pool.dtype),
+                      pool[blk, layer])            # (B, 2, R, bs, W)
+    return pool.at[blk, layer].set(pages)

@@ -116,8 +116,10 @@ def test_grow_table_extends_in_place_and_is_all_or_nothing():
 def test_scatter_gather_roundtrip_and_sink():
     cfg = get_config("stablelm-1.6b").smoke()
     pool = init_kv_pool(cfg, num_blocks=8, block_size=4)
-    L, two, N, KV, bs, hd = pool.shape
+    N, L, two, R, bs, W = pool.shape
     assert two == 2                  # K and V stacked: one-scatter appends
+    KV, hd = cfg.num_kv_heads, cfg.hd
+    assert R * W == KV * hd          # heads packed into lane rows
     S = 6
     rng = np.random.default_rng(0)
     krow = jnp.asarray(rng.standard_normal((L, KV, S, hd)), pool.dtype)
@@ -125,7 +127,7 @@ def test_scatter_gather_roundtrip_and_sink():
     blocks = jnp.asarray([3, 5], jnp.int32)
     pool = scatter_prefill_row(pool, blocks, krow, vrow)
     tables = jnp.zeros((1, 3), jnp.int32).at[0, :2].set(blocks)
-    ks, vs = gather_pages(pool[0], tables)       # (1, KV, 3*bs, hd) each
+    ks, vs = gather_pages(pool, 0, tables, hd)   # (1, KV, 3*bs, hd) each
     np.testing.assert_array_equal(np.asarray(ks[0, :, :S]),
                                   np.asarray(krow[0]))
     np.testing.assert_array_equal(np.asarray(vs[0, :, :S]),
@@ -133,24 +135,27 @@ def test_scatter_gather_roundtrip_and_sink():
     # table tail points at the sink: those positions read zeros
     np.testing.assert_array_equal(np.asarray(ks[0, :, 2 * bs:]), 0.0)
 
-    # append the 7th token (block idx 1, offset 2) on the active row:
-    # K and V land in ONE scatter
+    # append the 7th token (block idx 1, offset 2) on the active row of
+    # the LAST layer: K and V land in ONE in-place scatter on the whole pool
     new_k = jnp.full((1, KV, hd), 7.0, pool.dtype)
     new_v = jnp.full((1, KV, hd), 5.0, pool.dtype)
-    p_act = append_kv(pool[0], new_k, new_v, tables,
+    p_act = append_kv(pool, L - 1, new_k, new_v, tables,
                       jnp.asarray([S], jnp.int32), jnp.asarray([True]))
-    ks2, vs2 = gather_pages(p_act, tables)
+    ks2, vs2 = gather_pages(p_act, L - 1, tables, hd)
     np.testing.assert_array_equal(np.asarray(ks2[0, :, S]),
                                   np.asarray(new_k[0]))
     np.testing.assert_array_equal(np.asarray(vs2[0, :, S]),
                                   np.asarray(new_v[0]))
+    # the other layers' pages are untouched
+    np.testing.assert_array_equal(np.asarray(p_act[:, :L - 1]),
+                                  np.asarray(pool[:, :L - 1]))
     # inactive row: the write is redirected to the sink block
-    p_in = append_kv(pool[0], new_k * 9, new_v * 9, tables,
+    p_in = append_kv(pool, 0, new_k * 9, new_v * 9, tables,
                      jnp.asarray([S], jnp.int32), jnp.asarray([False]))
-    np.testing.assert_array_equal(np.asarray(p_in[:, 3:6]),
-                                  np.asarray(pool[0][:, 3:6]))
-    assert np.any(np.asarray(p_in[0, SINK_BLOCK]) == 63.0)
-    assert np.any(np.asarray(p_in[1, SINK_BLOCK]) == 45.0)
+    np.testing.assert_array_equal(np.asarray(p_in[3:6]),
+                                  np.asarray(pool[3:6]))
+    assert np.any(np.asarray(p_in[SINK_BLOCK, 0, 0]) == 63.0)
+    assert np.any(np.asarray(p_in[SINK_BLOCK, 0, 1]) == 45.0)
 
 
 def test_scatter_token_window_and_table_extension():
@@ -160,7 +165,7 @@ def test_scatter_token_window_and_table_extension():
                                      scatter_token_window, set_table_rows)
     cfg = get_config("stablelm-1.6b").smoke()
     pool = init_kv_pool(cfg, num_blocks=8, block_size=4)
-    L, _, N, KV, bs, hd = pool.shape
+    KV, hd = cfg.num_kv_heads, cfg.hd
     B, mb = 2, 4
     tables = jnp.zeros((B, mb), jnp.int32)
     tables = set_table_rows(tables, jnp.asarray([1], jnp.int32),
@@ -179,10 +184,12 @@ def test_scatter_token_window_and_table_extension():
     vs = jnp.asarray(rng.standard_normal((B, C, KV, hd)), pool.dtype)
     valid = np.zeros((B, C), bool)
     valid[1, :4] = True                  # 4 valid tokens, 1 past-prompt
-    p0 = scatter_token_window(pool[0], ks, vs, tables,
+    p0 = scatter_token_window(pool, 0, ks, vs, tables,
                               jnp.asarray([0, 6], jnp.int32),
                               jnp.asarray(valid))
-    got_k, got_v = gather_pages(p0, tables)
+    np.testing.assert_array_equal(np.asarray(p0[:, 1:]),
+                                  np.asarray(pool[:, 1:]))
+    got_k, got_v = gather_pages(p0, 0, tables, hd)
     np.testing.assert_array_equal(np.asarray(got_k[1, :, 6:10]),
                                   np.asarray(ks[1, :4]).swapaxes(0, 1))
     np.testing.assert_array_equal(np.asarray(got_v[1, :, 6:10]),
@@ -190,8 +197,8 @@ def test_scatter_token_window_and_table_extension():
     # row 0 (all invalid) and the past-prompt tail went to the sink: blocks
     # owned by nobody are untouched
     for untouched in (1, 4, 5, 7):
-        np.testing.assert_array_equal(np.asarray(p0[:, untouched]),
-                                      np.asarray(pool[0][:, untouched]))
+        np.testing.assert_array_equal(np.asarray(p0[untouched]),
+                                      np.asarray(pool[untouched]))
 
 
 def test_init_kv_pool_rejects_ssm():
@@ -299,13 +306,12 @@ def test_copy_blocks_forks_without_touching_source():
     padding convention is a harmless self-copy."""
     from repro.serve.kvcache import copy_blocks
     rng = np.random.default_rng(0)
-    pool = jnp.asarray(rng.normal(size=(2, 2, 6, 1, 4, 3)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(6, 2, 2, 1, 4, 3)), jnp.float32)
     before = np.asarray(pool)
     out = np.asarray(copy_blocks(
         pool, jnp.asarray([2, 4, SINK_BLOCK], jnp.int32),
         jnp.asarray([5, 1, SINK_BLOCK], jnp.int32)))
-    np.testing.assert_array_equal(out[:, :, 5], before[:, :, 2])
-    np.testing.assert_array_equal(out[:, :, 1], before[:, :, 4])
+    np.testing.assert_array_equal(out[5], before[2])
+    np.testing.assert_array_equal(out[1], before[4])
     for untouched in (0, 2, 3, 4):
-        np.testing.assert_array_equal(out[:, :, untouched],
-                                      before[:, :, untouched])
+        np.testing.assert_array_equal(out[untouched], before[untouched])

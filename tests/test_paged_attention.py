@@ -13,16 +13,19 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import flash_attention, paged_attention
+from repro.kernels.paged_attention import kv_pack
 from repro.models.attention import init_attention, paged_decode_attention
 from repro.serve.kvcache import gather_read_attention as _gather_oracle
 
 KEY = jax.random.PRNGKey(0)
 
 
-def _make_case(B, H, KV, hd, bs, mb, lengths, seed=0, dtype=jnp.float32):
-    """Random pool + disjoint per-row block tables covering ``lengths``.
+def _make_case(B, H, KV, hd, bs, mb, lengths, seed=0, dtype=jnp.float32,
+               L=3):
+    """Random ``L``-layer pool (KV heads packed into lane rows as
+    ``init_kv_pool`` packs them) + disjoint per-row block tables covering
+    ``lengths``.
 
     Rows with length < 0 are left entirely on the sink block (the engine's
     inactive-slot state); their length is clamped to 0 for the mask.
@@ -31,7 +34,8 @@ def _make_case(B, H, KV, hd, bs, mb, lengths, seed=0, dtype=jnp.float32):
     N = B * mb + 1
     ks = jax.random.split(KEY, 2)
     q = jax.random.normal(ks[0], (B, H, hd), dtype)
-    pool_kv = jax.random.normal(ks[1], (2, N, KV, bs, hd), dtype)
+    P = kv_pack(KV, hd)
+    pool_kv = jax.random.normal(ks[1], (N, L, 2, KV // P, bs, P * hd), dtype)
     tables = np.zeros((B, mb), np.int32)
     free = list(rng.permutation(np.arange(1, N)))
     for b in range(B):
@@ -52,9 +56,10 @@ def test_paged_matches_gather_across_gqa_and_ragged_lengths(impl, H, KV):
     # not divide pos+1 except row 3), near-capacity
     lengths = [0, 7, bs - 1, 2 * bs, mb * bs - 1]
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, lengths)
-    out = paged_attention(q, pool_kv, tables, ln, impl=impl)
-    ref = _gather_oracle(q, pool_kv, tables, ln)
-    assert jnp.max(jnp.abs(out - ref)) < 2e-5
+    for layer in range(pool_kv.shape[0]):
+        out = paged_attention(q, pool_kv, tables, ln, layer, impl=impl)
+        ref = _gather_oracle(q, pool_kv, layer, tables, ln)
+        assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
@@ -65,8 +70,8 @@ def test_paged_inactive_sink_rows(impl):
     lengths = [5, -1, 20, -1]              # rows 1 and 3 are sink-parked
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, lengths)
     assert int(tables[1].sum()) == 0 and int(tables[3].sum()) == 0
-    out = paged_attention(q, pool_kv, tables, ln, impl=impl)
-    ref = _gather_oracle(q, pool_kv, tables, ln)
+    out = paged_attention(q, pool_kv, tables, ln, 1, impl=impl)
+    ref = _gather_oracle(q, pool_kv, 1, tables, ln)
     assert bool(jnp.all(jnp.isfinite(out)))
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
@@ -76,8 +81,8 @@ def test_paged_inactive_sink_rows(impl):
 def test_paged_block_size_not_dividing_pos(impl, bs, pos):
     B, H, KV, hd, mb = 2, 4, 2, 16, 4
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, [pos, pos % bs])
-    out = paged_attention(q, pool_kv, tables, ln, impl=impl)
-    ref = _gather_oracle(q, pool_kv, tables, ln)
+    out = paged_attention(q, pool_kv, tables, ln, 2, impl=impl)
+    ref = _gather_oracle(q, pool_kv, 2, tables, ln)
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
 
@@ -90,16 +95,18 @@ def test_paged_decode_attention_impl_switch_parity(impl):
     B, mb, bs, N = 3, 4, 4, 16
     p = init_attention(jax.random.PRNGKey(1), cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (B, 1, cfg.d_model))
+    P = kv_pack(cfg.num_kv_heads, cfg.hd)
     pool_kv = jax.random.normal(
-        jax.random.PRNGKey(3), (2, N, cfg.num_kv_heads, bs, cfg.hd))
+        jax.random.PRNGKey(3),
+        (N, 2, 2, cfg.num_kv_heads // P, bs, P * cfg.hd))
     rng = np.random.default_rng(0)
     perm = rng.permutation(np.arange(1, N))[:B * mb].reshape(B, mb)
     tables = jnp.asarray(perm.astype(np.int32))
     pos = jnp.asarray([0, 5, 11], jnp.int32)
     active = jnp.asarray([True, True, False])
-    y_ref, pool_ref = paged_decode_attention(p, x, cfg, pool_kv, tables,
+    y_ref, pool_ref = paged_decode_attention(p, x, cfg, pool_kv, 1, tables,
                                              pos, active, impl="gather")
-    y, pool = paged_decode_attention(p, x, cfg, pool_kv, tables,
+    y, pool = paged_decode_attention(p, x, cfg, pool_kv, 1, tables,
                                      pos, active, impl=impl)
     np.testing.assert_array_equal(np.asarray(pool), np.asarray(pool_ref))
     act = np.asarray(active)

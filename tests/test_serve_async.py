@@ -276,3 +276,33 @@ def test_engine_fence_blocks_not_reallocated_while_chunk_in_flight(setup):
             assert eng.stats["preempted"] >= 1
         assert not violations, violations
         assert all(o.shape == (16,) for o in outs)
+
+
+#: greedy tokens of the engine below as served before the pool moved to the
+#: in-place, packed ``(N, L, 2, R, block, W)`` layout (KV pool carried
+#: through the layer loop instead of scanned as xs/ys): the rewrite must
+#: not move a single token on the oracle read path
+_GOLDEN_PRE_INPLACE = [
+    [287, 291, 409, 292, 269, 200, 180, 198, 244, 114, 378, 180, 410],
+    [446, 27, 457, 473, 80, 484, 466, 114, 199, 199, 148, 89, 348],
+    [166, 182, 74, 87, 78, 108, 59, 239, 131, 61, 366, 91, 285],
+    [30, 347, 394, 394, 71, 71, 347, 394, 356, 180, 197, 41, 71],
+]
+
+
+@pytest.mark.parametrize("async_decode", [False, True])
+def test_inplace_pool_keeps_golden_tokens(setup, async_decode):
+    """Chunked prefill (prompts up to 30 tokens through 8-token windows)
+    and mid-decode block growth (4-token blocks, 13 new tokens) on the
+    gather oracle reproduce the pre-rewrite greedy tokens exactly."""
+    cfg, params = setup
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, size=s).astype(np.int32)
+               for s in (5, 23, 11, 30)]
+    with ServeEngine(cfg, params, async_decode=async_decode,
+                     paged_impl="gather", decode_chunk=3, block_size=4,
+                     prefill_chunk=8, kv_blocks=64, max_batch=4) as eng:
+        outs = eng.generate(prompts, max_new=13)
+        assert eng.stats["prefill_windows"] >= 2
+        assert eng.stats["grown_blocks"] > 0
+    assert [list(map(int, o)) for o in outs] == _GOLDEN_PRE_INPLACE

@@ -108,7 +108,7 @@ assert serve_kv_cache_spec(cfg, ctx) == P(None, None, "model", None, None)
 
 # ---- lowered decode-chunk HLO: zero all-reduces, and no all-gather whose
 # single largest operand/result approaches the per-device pool shard (the
-# pool is (L, 2, N, KV/4, bs, hd) per device and must NEVER be gathered)
+# pool is (N, L, 2, R/4, bs, W) per device and must NEVER be gathered)
 params = lm.init_params(cfg, jax.random.PRNGKey(0))
 eng = ServeEngine(cfg, params, ctx=ctx, decode_chunk=4, max_batch=4,
                   kv_blocks=48, block_size=8, max_admit=2)

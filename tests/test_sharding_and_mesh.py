@@ -55,14 +55,9 @@ import jax
 import jax.numpy as jnp
 import dataclasses
 from repro.configs import get_config, SHAPES_BY_NAME
-from repro.launch.mesh import make_ctx
+from repro.launch.mesh import make_ctx, small_mesh
 from repro.train.train_step import train_input_specs, make_decode_step
-# jax<0.5 has no jax.sharding.AxisType (axes default to Auto there anyway)
-try:
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-except (AttributeError, TypeError):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = small_mesh(data=2, model=4)
 ctx = make_ctx(mesh)
 cfg = dataclasses.replace(get_config("stablelm-1.6b").smoke(),
                           d_model=128, vocab_size=1024, num_heads=8,
