@@ -13,16 +13,14 @@ fsync cost is a disk property, not engine overhead) and detached, and
 the attached-path tokens/sec must stay within budget of the detached
 path.
 
-Methodology matches the observability gate (`obs_overhead_gate.py`):
-repetitions are INTERLEAVED off/on and each mode is scored by its BEST
+Repetitions are INTERLEAVED off/on and each mode is scored by its BEST
 repetition — deterministic per-transition work survives into the
 cleanest rep, shared-container CPU throttling does not. Both modes run
 the SAME compiled programs (``ServeEngine.set_journal`` rebinds at
 idle; journaling never changes compiled shapes).
 
 Budget: the ``REPRO_JOURNAL_GATE_BUDGET`` env var (fraction, default
-0.05 — journal appends hit the filesystem, so the budget is the CI
-obs-gate slack, not the local 2%).
+0.05 — journal appends hit the filesystem).
 """
 from __future__ import annotations
 

@@ -31,12 +31,6 @@ serve_mesh — tensor-parallel sharded serving: --mesh-model N KV-head-
           set XLA_FLAGS=--xla_force_host_platform_device_count=N up front
 paged_decode — gather-free paged decode read path vs the gather oracle
           across pool occupancies; honors --quick
-decode_overlap — async decode lookahead vs the synchronous decode loop:
-          per-cycle dispatch/sync/bookkeeping wall-time breakdown and
-          host-gap fraction across decode-chunk sizes; honors --quick
-obs_gate — observability overhead gate: serve tok/s with the obs stack
-          enabled must stay within REPRO_OBS_GATE_BUDGET (default 2%)
-          of disabled; honors --quick
 journal_gate — durability overhead gate: serve tok/s with the request
           WAL attached must stay within REPRO_JOURNAL_GATE_BUDGET
           (default 5%) of detached; honors --quick
@@ -46,7 +40,7 @@ Each completed suite drops ``BENCH_<suite>.json`` into --bench-dir
 metrics (``tok_per_s`` / ``p50_ms`` / ``p99_ms`` where a suite reports
 them), and provenance (git sha + ISO-8601 UTC timestamp) — the
 machine-readable perf trajectory that used to exist only as stdout CSV.
-The serve, serve_slo and decode_overlap suites also write their run's
+The serve and serve_slo suites also write their run's
 Chrome trace-event JSON (``TRACE_<suite>.json``, Perfetto-loadable)
 alongside.
 """
@@ -137,10 +131,9 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    from . import (decode_overlap_microbench, fig9_micro_random_dag,
-                   fig11_corun_throughput, fig13_lsdnn,
-                   fig17_conditional_memory, fig21_incremental_timing,
-                   journal_overhead_gate, obs_overhead_gate,
+    from . import (fig9_micro_random_dag, fig11_corun_throughput,
+                   fig13_lsdnn, fig17_conditional_memory,
+                   fig21_incremental_timing, journal_overhead_gate,
                    paged_decode_microbench, pipeline_throughput,
                    roofline_report, serve_continuous, serve_slo,
                    table2_task_overhead)
@@ -182,10 +175,6 @@ def main() -> None:
         "serve_mesh": lambda: _serve_mesh_rows(),
         "paged_decode":
             lambda: paged_decode_microbench.bench(quick=args.quick),
-        "decode_overlap":
-            lambda: decode_overlap_microbench.bench(
-                quick=args.quick, trace_path=_trace("decode_overlap")),
-        "obs_gate": lambda: obs_overhead_gate.bench(quick=args.quick),
         "journal_gate":
             lambda: journal_overhead_gate.bench(quick=args.quick),
     }
@@ -196,8 +185,6 @@ def main() -> None:
               "mesh_model_env": os.environ.get("REPRO_MESH_MODEL", ""),
               "paged_impl_env": os.environ.get("REPRO_PAGED_IMPL", ""),
               "async_decode_env": os.environ.get("REPRO_ASYNC_DECODE", ""),
-              "obs_gate_budget_env":
-                  os.environ.get("REPRO_OBS_GATE_BUDGET", ""),
               "journal_gate_budget_env":
                   os.environ.get("REPRO_JOURNAL_GATE_BUDGET", "")}
     only = [s for s in args.only.split(",") if s]

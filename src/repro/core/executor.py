@@ -24,6 +24,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from ..obs.tracing import span
 from .atomic import AtomicInt
 from .graph import (ACCEL, HOST, Node, Subflow, Task, Taskflow, TaskType)
 from .notifier import EventNotifier, Waiter
@@ -539,7 +540,8 @@ class Executor:
                     continue  # goto Line 1
             if obs:
                 obs.on_sleep(w.id, w.domain)
-            notifier.commit_wait(w.waiter)
+            with span("executor.sleep", domain=w.domain):
+                notifier.commit_wait(w.waiter)
             if obs:
                 obs.on_wake(w.id, w.domain)
             return None, True  # loop in worker_loop re-enters the protocol

@@ -4,14 +4,18 @@ The Taskflow paper ships tfprof (§VI) — a built-in profiler whose
 per-worker timelines make the runtime's scheduling decisions visible.
 This package is the serve-stack analogue for our reproduction:
 
-* :mod:`repro.obs.tracing` — :class:`Tracer`, a thread-safe ring buffer
-  of ``(name, track, t_start, t_end, args)`` spans (request lifecycle on
-  per-slot tracks, engine cycle phases on the ``"engine"`` track,
-  pipeline pipe bodies on ``"lineN"`` tracks);
+* :mod:`repro.obs.tracing` — :class:`span`, the one way code marks a
+  span: always a ``jax.profiler.TraceAnnotation`` (the profiler's trace,
+  on the device's clock), and a record in a :class:`Tracer` when one is
+  attached — a thread-safe ring buffer of ``(name, track, t_start,
+  t_end, args)`` spans (request lifecycle on per-slot tracks, the
+  engine's ``serve.*`` phases on the ``"engine"`` track, ``pipe.*``
+  bodies on ``"lineN"`` tracks);
 * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` of named counters,
   gauges and exponential-bucket histograms (pool occupancy, queue depth,
   preempt/stall counts, TTFT, queue wait, per-cycle dispatch/sync/
-  bookkeeping seconds) with a JSON-able ``snapshot()``;
+  bookkeeping seconds read from the spans) with a JSON-able
+  ``snapshot()``;
 * :mod:`repro.obs.export` — Chrome trace-event JSON export (loads in
   Perfetto / ``chrome://tracing``) and the ``--stats-interval`` one-line
   :class:`StatsLogger`.
@@ -29,11 +33,11 @@ from typing import Optional
 
 from .export import StatsLogger, chrome_trace_events, export_chrome_trace
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .tracing import TRACK_ENGINE, Span, Tracer
+from .tracing import TRACK_ENGINE, Span, Tracer, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Span", "Tracer", "TRACK_ENGINE",
+    "Span", "Tracer", "TRACK_ENGINE", "span",
     "StatsLogger", "chrome_trace_events", "export_chrome_trace",
     "Observability", "env_enabled", "from_env",
 ]

@@ -1,46 +1,50 @@
-"""Low-overhead span recorder for the serve-layer timeline (tfprof-style).
+"""Spans of the serve stack, on two clocks (tfprof-style).
 
 The Taskflow paper ships a built-in profiler (tfprof, §VI) that records
 per-worker task intervals and renders them as an execution timeline; this
-module is the serve-stack analogue. A :class:`Tracer` holds a bounded RING
-BUFFER of completed spans — plain ``(name, track, t_start, t_end, args)``
-tuples on the ``time.perf_counter`` clock — that the engine, the pipeline
-and the launcher append to from worker threads:
+module is the serve-stack analogue. :class:`span` is the one way code
+marks a span. It has two sinks:
 
-* **tracks** partition the timeline the way tfprof partitions by worker:
-  one track per decode slot (``"slot3"``) carrying that seat's request
-  lifecycle spans (queued → admitted → prefill_window → decode →
-  stalled → retired), one ``"engine"`` track carrying the per-cycle phase
-  spans (admission, merge, prefill_window, growth, dispatch, sync,
-  bookkeeping, cycle), and one ``"lineN"`` track per pipeline line with
-  the raw pipe-body intervals (the promotion of
-  :attr:`repro.pipeline.Pipeline.stage_times` into spans);
-* an *instant* is a span with ``t_end == t_start`` (exported as a Chrome
-  trace instant event) — used for point events like ``retired`` and
-  ``preempted``.
+* the **profiler's trace**: every span enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  session (``jax.profiler.trace(dir)``) records it on the host plane, on
+  the same clock as the device's operations. Outside a session the
+  annotation is the profiler's own no-op switch (well under a
+  microsecond). JAX is looked up only once something has imported it, so
+  the runtime (``core/``, ``pipeline/``) stays importable without it;
+* a :class:`Tracer`, when one is passed and enabled: a bounded RING
+  BUFFER of completed spans — plain ``(name, track, t_start, t_end,
+  args)`` tuples on the ``time.perf_counter`` clock — exported to Chrome
+  trace JSON by :mod:`repro.obs.export` (``launch/serve.py --trace``).
 
-Design constraints (the serve hot loop calls this every cycle):
+Tracks partition the ring buffer's timeline the way tfprof partitions by
+worker: one track per decode slot (``"slot3"``) carrying that seat's
+request lifecycle spans (queued → admitted → prefill_window → decode →
+stalled → retired, recorded after the fact with :meth:`Tracer.add`), one
+``"engine"`` track carrying the engine's ``serve.*`` phase spans, and one
+``"lineN"`` track per pipeline line with the ``pipe.<stage>`` body spans.
+An *instant* is a span with ``t_end == t_start`` (exported as a Chrome
+trace instant event) — used for point events like ``retired`` and
+``preempted``.
+
+Design constraints (the serve hot loop opens about ten spans a cycle):
 
 * ``add`` is one lock acquisition + one list write; the buffer never
   grows past ``capacity`` — old spans are overwritten oldest-first and
   counted in :attr:`dropped` (a trace that wrapped says so instead of
   silently lying);
-* a disabled tracer (``enabled=False``) returns before touching the lock,
-  and every instrumentation site in the engine additionally guards on its
-  obs handle being ``None``, so the disabled path costs attribute checks
-  only (the <2%% overhead budget on the quick serve bench).
-
-Export to Chrome trace-event JSON (Perfetto / ``chrome://tracing``) lives
-in :mod:`repro.obs.export`.
+* a disabled or absent tracer costs the span an attribute check, so a
+  span with no tracer and no profiler session costs the annotation's
+  enter and exit and two clock reads.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "TRACK_ENGINE"]
+__all__ = ["Span", "Tracer", "TRACK_ENGINE", "span"]
 
 #: the engine-cycle track name (one per engine; slot tracks are "slotN")
 TRACK_ENGINE = "engine"
@@ -99,19 +103,6 @@ class Tracer:
             t = time.perf_counter()
         self.add(name, track, t, t, args)
 
-    @contextmanager
-    def span(self, name: str, track: str,
-             args: Optional[Dict[str, Any]] = None):
-        """Context manager: record the wrapped block as one span."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, track, t0, time.perf_counter(), args)
-
     # ---------------------------------------------------------------- reading
     def spans(self) -> List[Span]:
         """A chronological (oldest-first) copy of the buffered spans."""
@@ -129,3 +120,71 @@ class Tracer:
             self._buf = []
             self._write = 0
             self.dropped = 0
+
+
+#: ``jax.profiler.TraceAnnotation``, found once JAX has been imported
+_annotation = None
+
+
+def _annotation_type():
+    """The profiler's annotation type, or None while JAX is not imported
+    (no profiler session can be on then) or not installed."""
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """Mark the wrapped block as one span named ``name``, in both sinks
+    (see the module docstring)::
+
+        with span("serve.sync", tracer):
+            toks = np.asarray(toks)
+
+    ``args`` ride on both sinks: as the annotation's metadata and as the
+    ring-buffer span's args. :meth:`annotate` adds args known only inside
+    the block. ``t0``/``t1`` hold the block's ``perf_counter`` bounds
+    whatever the sinks, so callers time the block from the span itself.
+    """
+
+    __slots__ = ("name", "track", "args", "t0", "t1", "_tracer", "_ann")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None,
+                 track: str = TRACK_ENGINE, **args: Any) -> None:
+        self.name = name
+        self.track = track
+        self.args = args
+        self.t0 = self.t1 = 0.0
+        self._tracer = tracer
+        self._ann = None
+
+    def annotate(self, **args: Any) -> None:
+        """Add args to the open span (both sinks)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "span":
+        ann = _annotation_type()
+        if ann is not None:
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            self._tracer.add(self.name, self.track, self.t0, self.t1,
+                             self.args or None)

@@ -60,12 +60,12 @@ from __future__ import annotations
 
 import enum
 import threading
-import time
 from typing import Callable, Dict, List, Optional
 
 from ..core.atomic import AtomicInt
 from ..core.executor import Executor, Topology
 from ..core.graph import HOST, Task, Taskflow
+from ..obs.tracing import span
 
 __all__ = ["PipeType", "Pipe", "Pipeflow", "Pipeline"]
 
@@ -184,12 +184,14 @@ class Pipeline:
                           for _ in range(num_lines)]
         # per-(line, pipe) cumulative wall time inside the stage body; a
         # slot runs exclusively (its join counter serialises visits), so
-        # plain int accumulation is race-free
-        self._stage_ns = [[0] * len(pipes) for _ in range(num_lines)]
-        # optional repro.obs.Tracer: when set, every pipe-body interval is
-        # also recorded as a span on a per-line track ("line0", "line1",
-        # ...) — the stage_times aggregate, promoted to a timeline. Plain
-        # attribute so callers can attach/detach between runs.
+        # plain float accumulation is race-free
+        self._stage_s = [[0.0] * len(pipes) for _ in range(num_lines)]
+        # every pipe body is a "pipe.<name>" span (repro.obs.tracing.span):
+        # always in the profiler's trace, and in this optional
+        # repro.obs.Tracer when set, on a per-line track ("line0",
+        # "line1", ...) — the stage_times aggregate, promoted to a
+        # timeline. Plain attribute so callers can attach/detach between
+        # runs.
         self.tracer = None
         self._num_tokens = 0
         self._num_deferrals = AtomicInt(0)
@@ -255,8 +257,8 @@ class Pipeline:
         worst one stage-visit stale)."""
         out: Dict[str, float] = {}
         for s, pipe in enumerate(self._pipes):
-            ns = sum(self._stage_ns[l][s] for l in range(self._num_lines))
-            out[pipe.name] = out.get(pipe.name, 0.0) + ns / 1e9
+            secs = sum(self._stage_s[l][s] for l in range(self._num_lines))
+            out[pipe.name] = out.get(pipe.name, 0.0) + secs
         return out
 
     @property
@@ -287,6 +289,7 @@ class Pipeline:
         pipe = self._pipes[s]
         serial = pipe.kind is PipeType.SERIAL
         counters = self._counters
+        span_name, track = f"pipe.{pipe.name}", f"line{l}"
 
         def run_slot() -> tuple:
             pf = self._pipeflows[l]
@@ -306,13 +309,9 @@ class Pipeline:
                 pf._stopped = False
                 pf._defer_on = None
                 while True:
-                    _t = time.perf_counter_ns()
-                    self._invoke(pipe, pf)
-                    _t2 = time.perf_counter_ns()
-                    self._stage_ns[l][s] += _t2 - _t
-                    if self.tracer is not None:
-                        self.tracer.add(pipe.name, f"line{l}",
-                                        _t / 1e9, _t2 / 1e9)
+                    with span(span_name, self.tracer, track) as body:
+                        self._invoke(pipe, pf)
+                    self._stage_s[l][s] += body.seconds
                     if pf._stopped:
                         self._stopped = True
                         return ()  # break both chains: in-flight drain
@@ -340,13 +339,9 @@ class Pipeline:
                     return ()
                 self._num_tokens += 1
             else:
-                _t = time.perf_counter_ns()
-                self._invoke(pipe, pf)
-                _t2 = time.perf_counter_ns()
-                self._stage_ns[l][s] += _t2 - _t
-                if self.tracer is not None:
-                    self.tracer.add(pipe.name, f"line{l}",
-                                    _t / 1e9, _t2 / 1e9)
+                with span(span_name, self.tracer, track) as body:
+                    self._invoke(pipe, pf)
+                self._stage_s[l][s] += body.seconds
             if s == S - 1:
                 # token fully done: wake a deferred token waiting on it.
                 # Done BEFORE this task's pending-tally so the topology

@@ -141,9 +141,8 @@ a preempted row's blocks re-enter the pool only after the engine has
 synced past the device work that could still write them (the
 ``BlockPool.free_deferred`` / ``release_deferred`` fence). Greedy tokens
 are bit-identical to the synchronous engine, which remains the reference
-path (default off). ``ServeEngine.overlap_stats`` exposes the per-cycle
-dispatch/wait/bookkeeping/host-gap breakdown; see
-``benchmarks/decode_overlap_microbench.py``.
+path (default off). Both loops mark their phases as ``serve.dispatch`` /
+``serve.sync`` / ``serve.bookkeeping`` spans (Observability below).
 
 Tensor-parallel sharded serving
 -------------------------------
@@ -191,15 +190,20 @@ Observability
 environment — turns on the serve-layer observability stack
 (:mod:`repro.obs`; see ``docs/observability.md`` for a quick-start):
 
-* **Spans** (ring-buffer :class:`repro.obs.Tracer`): each decode SLOT is a
-  track carrying its seated request's lifecycle — ``queued`` → ``admitted``
-  → ``prefill``/``prefill_window`` → ``decode`` → ``stalled`` — plus
+* **Spans** (:func:`repro.obs.tracing.span`): the engine's phases
+  (``serve.admission``, ``serve.prefill``, ``serve.merge``,
+  ``serve.prefill_window``, ``serve.window_sync``, ``serve.growth``, and
+  ``serve.cycle`` with its ``serve.dispatch``/``serve.sync``/
+  ``serve.bookkeeping`` split) and the pipe bodies (``pipe.admit`` …
+  ``pipe.complete``) always reach a ``jax.profiler`` trace, obs or not,
+  on the device trace's clock. With obs attached they also land in the
+  ring-buffer :class:`repro.obs.Tracer`: ``serve.*`` on the ``"engine"``
+  track, ``pipe.*`` on ``lineN`` tracks (``Pipeline.stage_times``
+  promoted to a timeline); and each decode SLOT is a track carrying its
+  seated request's lifecycle — ``queued`` → ``admitted`` →
+  ``prefill``/``prefill_window`` → ``decode`` → ``stalled`` — plus
   ``retired``/``preempted`` instants; a preempted request re-enters with a
-  fresh queued/admitted chain, so the track replays every re-entry. The
-  ``"engine"`` track carries per-cycle phases (``admission``, ``growth``,
-  ``cycle`` with its ``dispatch``/``sync``/``bookkeeping`` split), and
-  ``lineN`` tracks carry the raw pipeline pipe-body intervals
-  (``Pipeline.stage_times`` promoted to a timeline).
+  fresh queued/admitted chain, so the track replays every re-entry.
 * **Metrics** (:class:`repro.obs.MetricsRegistry`): counters
   ``serve.tokens_out`` / ``serve.requests.{admitted,retired,preempted,
   stalled}`` / ``serve.{shed,expired,cancelled,watchdog_fires,
@@ -209,7 +213,7 @@ environment — turns on the serve-layer observability stack
   ``pool.blocks_{free,used,deferred,shared,parked,reserved}``;
   histograms ``serve.ttft_s`` (plus lazy per-tier
   ``serve.ttft_s.tierN``) / ``serve.queue_wait_s`` /
-  ``engine.{cycle,dispatch,chunk_sync,book,gap,chunk}_s``; per-slot
+  ``engine.{cycle,dispatch,chunk_sync,book}_s``; per-slot
   ``cow_fork`` trace instants mark copy-on-write block forks.
 * **Export**: ``obs.export(path)`` writes Chrome trace-event JSON that
   loads directly in Perfetto (https://ui.perfetto.dev) or
@@ -220,9 +224,9 @@ environment — turns on the serve-layer observability stack
   ``first_token_at`` / ``finished_at`` and the derived ``ttft`` /
   ``queue_wait``).
 
-A ``None`` obs handle (the default) keeps every hot path to a single
-attribute check; ``benchmarks/obs_overhead_gate.py`` enforces the
-enabled-path budget (2% local, 5% CI).
+A ``None`` obs handle (the default) leaves each span its profiler
+annotation and two clock reads, and every other hot path a single
+attribute check.
 
 Durable serving
 ---------------

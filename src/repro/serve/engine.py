@@ -90,9 +90,10 @@ The new scheduling hazards this opens are closed explicitly:
 Greedy tokens are bit-identical to the synchronous engine (same compiled
 chunk program, same carry values — asserted on the ``gather`` oracle in
 ``tests/test_serve_async.py``); the synchronous path remains the
-reference. ``self.overlap_stats`` tracks the per-cycle dispatch / wait /
-bookkeeping / host-gap breakdown that
-``benchmarks/decode_overlap_microbench.py`` reports.
+reference. Each cycle's phases are ``serve.*`` spans
+(:func:`repro.obs.tracing.span`): ``serve.dispatch``, ``serve.sync`` and
+``serve.bookkeeping`` in both loops, so a ``jax.profiler`` trace shows
+which host phase the device waited on.
 
 SSM / hybrid architectures (mamba, zamba2) serve through the SAME
 resident pipeline via a fixed-slot recurrent-state pool: prefilled
@@ -139,6 +140,7 @@ from ..distributed.sharding import (ShardCtx, manual_serve_map,
 from ..models import lm
 from ..obs import TRACK_ENGINE
 from ..obs import from_env as _obs_from_env
+from ..obs.tracing import span
 from ..pipeline import DataPipe, DataPipeline, PipeType
 from .errors import (DeadlineExceeded, EngineClosed, Overloaded,
                      RequestCancelled, RowFailed, SnapshotCorrupt,
@@ -442,16 +444,6 @@ class ServeEngine:
         self._slot_gen = np.zeros((B,), np.int64)
         self._pending: Optional[Dict[str, Any]] = None   # in-flight chunk
         self._window_pending: Optional[Dict[str, Any]] = None
-        #: per-decode-cycle wall-time breakdown (all modes): dispatch_s =
-        #: chunk launch, wait_s = blocking device sync, book_s = host
-        #: bookkeeping, gap_s = host time with NO device work in flight
-        #: (the host gap the async mode exists to close)
-        #: ``min_chunk_s`` is the cleanest observed upload+launch+block
-        #: interval of a sync-mode cycle — the microbench's device-time
-        #: calibration constant (0 until a sync chunk has run)
-        self.overlap_stats = {"cycles": 0, "dispatch_s": 0.0, "wait_s": 0.0,
-                              "book_s": 0.0, "gap_s": 0.0, "total_s": 0.0,
-                              "min_chunk_s": 0.0}
         self._slot_req: List[Optional[ServeRequest]] = [None] * B
         self._slot_out: List[Optional[List[int]]] = [None] * B
         self._slot_phase: List[Optional[str]] = [None] * B  # prefill|decode
@@ -631,8 +623,6 @@ class ServeEngine:
             "dispatch": metrics.histogram("engine.dispatch_s"),
             "sync": metrics.histogram("engine.chunk_sync_s"),
             "book": metrics.histogram("engine.book_s"),
-            "gap": metrics.histogram("engine.gap_s"),
-            "chunk": metrics.histogram("engine.chunk_s"),
             "shed": metrics.counter("serve.shed"),
             "expired": metrics.counter("serve.expired"),
             "cancelled": metrics.counter("serve.cancelled"),
@@ -1123,8 +1113,11 @@ class ServeEngine:
             return list(self._stage_log or [])
 
     def _st_admit(self, pf):
-        t_adm = time.perf_counter()
-        self._wd_beat = t_adm
+        with span("serve.admission", self._tr) as sp:
+            return self._admit(pf, sp)
+
+    def _admit(self, pf, sp):
+        self._wd_beat = sp.t0
         epoch = self._reset_epoch
         with self._state_lock:
             occupied = any(r is not None for r in self._slot_req)
@@ -1269,9 +1262,7 @@ class ServeEngine:
                     self._journal.admit(g[0])
             if self._mh is not None:
                 self._mh["admitted"].inc(len(group))
-            if self._tr is not None:
-                self._tr.add("admission", TRACK_ENGINE, t_adm, now,
-                             {"reqs": [g[0].id for g in group]})
+            sp.annotate(req=[g[0].id for g in group])
             self._log("admit", pf.token, [g[0].id for g in group])
             return ("admit", group)
         if waiting and deps:
@@ -1291,9 +1282,7 @@ class ServeEngine:
         with self._state_lock:
             self._cycle_tokens.add(pf.token)
             self.stats["pump_cycles"] += 1
-        if self._tr is not None:
-            self._tr.add("admission", TRACK_ENGINE, t_adm,
-                         time.perf_counter(), {"pump": True})
+        sp.annotate(pump=True)
         self._log("pump", pf.token, None)
         return ("pump", None)
 
@@ -1302,7 +1291,9 @@ class ServeEngine:
         if kind != "admit":
             return msg
         try:
-            return self._prefill_group(pf, payload)
+            with span("serve.prefill", self._tr,
+                      req=[g[0].id for g in payload]):
+                return self._prefill_group(pf, payload)
         except Exception as exc:           # per-group failure isolation
             return self._prefill_failed(pf, payload, exc)
 
@@ -1735,19 +1726,22 @@ class ServeEngine:
         toks, valid = self._wp_toks, self._wp_valid
         start, last_idx = self._wp_start, self._wp_last_idx
         ks = {}
-        for b in pref:
-            prompt = self._slot_prompt[b]
-            s = int(self._pref_pos[b])
-            k = min(C, len(prompt) - s)
-            toks[b, :k] = prompt[s:s + k]
-            valid[b, :k] = True
-            valid[b, k:] = False
-            start[b] = s
-            last_idx[b] = min(len(prompt) - 1 - s, C - 1)
-            ks[b] = k
-        first, pkv = self._prefill_window(
-            self.params, self._pkv, self._tables_dev, jnp.asarray(toks),
-            jnp.asarray(start), jnp.asarray(valid), jnp.asarray(last_idx))
+        with span("serve.prefill_window", self._tr,
+                  req=[self._slot_req[b].id for b in pref]):
+            for b in pref:
+                prompt = self._slot_prompt[b]
+                s = int(self._pref_pos[b])
+                k = min(C, len(prompt) - s)
+                toks[b, :k] = prompt[s:s + k]
+                valid[b, :k] = True
+                valid[b, k:] = False
+                start[b] = s
+                last_idx[b] = min(len(prompt) - 1 - s, C - 1)
+                ks[b] = k
+            first, pkv = self._prefill_window(
+                self.params, self._pkv, self._tables_dev, jnp.asarray(toks),
+                jnp.asarray(start), jnp.asarray(valid),
+                jnp.asarray(last_idx))
         self._pkv = pkv
         with self._state_lock:
             self.stats["prefill_windows"] += 1
@@ -1763,7 +1757,8 @@ class ServeEngine:
         chunk on the pool's dependency chain, so by then its outputs are
         ready and the ``np.asarray`` below does not stall the loop — and
         scatters the transitions onto the device carry."""
-        first = np.asarray(pend["first"])
+        with span("serve.window_sync", self._tr):
+            first = np.asarray(pend["first"])
         now = time.perf_counter()
         t_rows, t_len, t_last, t_rem = [], [], [], []
         done = []
@@ -2364,112 +2359,96 @@ class ServeEngine:
 
     def _st_decode(self, pf, msg):
         self._wd_beat = time.perf_counter()
-        try:
-            if self.async_decode:
-                out = self._st_decode_async(pf, msg)
-            else:
-                out = self._st_decode_sync(pf, msg)
-        except Exception as exc:       # per-row failure isolation
-            out = self._isolate_failure(pf, exc)
+        with span("serve.cycle", self._tr) as cyc:
+            try:
+                if self.async_decode:
+                    out = self._st_decode_async(pf, msg, cyc)
+                else:
+                    out = self._st_decode_sync(pf, msg, cyc)
+            except Exception as exc:       # per-row failure isolation
+                out = self._isolate_failure(pf, exc)
+        if self._mh is not None:
+            self._mh["cycle"].record(cyc.seconds)
         self._wd_beat = time.perf_counter()
         return out
 
-    def _st_decode_sync(self, pf, msg):
-        t0 = time.perf_counter()
+    def _end_cycle(self, cyc, emitted: int, dispatch_s: float,
+                   sync_s: float) -> None:
+        """Fold a decode cycle into the service rate and the per-cycle
+        histograms; ``book_s`` is the cycle's host time outside the
+        dispatch and the sync."""
+        spent = time.perf_counter() - cyc.t0
+        self._note_rate(emitted, spent)
+        cyc.annotate(emitted=emitted)
+        if self._mh is not None:
+            mh = self._mh
+            mh["dispatch"].record(dispatch_s)
+            mh["sync"].record(sync_s)
+            mh["book"].record(spent - dispatch_s - sync_s)
+            mh["tokens_out"].inc(emitted)
+
+    def _st_decode_sync(self, pf, msg, cyc):
+        tr = self._tr
         kind, payload = msg
         if kind == "admit":
-            if self.paged:
-                self._merge_group(pf, payload)
-            else:
-                self._merge_group_slots(pf, payload)
+            with span("serve.merge", tr):
+                if self.paged:
+                    self._merge_group(pf, payload)
+                else:
+                    self._merge_group_slots(pf, payload)
         self._sweep_seated(pf)
         if self.paged:
-            tg0 = time.perf_counter()
-            self._cow_guard(pf)
+            with span("serve.growth", tr):
+                self._cow_guard(pf)
             self._window_prefill_step(pf)
-            self._grow_or_preempt(pf)
-            if self._tr is not None:
-                self._tr.add("growth", TRACK_ENGINE, tg0,
-                             time.perf_counter())
+            with span("serve.growth", tr):
+                self._grow_or_preempt(pf)
         rem_before = self._rem.copy()
         if not (rem_before > 0).any():
             self._log("decode", pf.token, 0)
             return ("cycle", (self._reset_epoch, self._collect_finished()))
         n = self.decode_chunk
-        t1 = time.perf_counter()
-        if self.paged:
-            pkv, tok, ln, rm, toks = self._decode_paged(
-                self.params, self._pkv, self._tables_dev,
-                jnp.asarray(self._lengths), jnp.asarray(self._last),
-                jnp.asarray(self._rem), n=n)
-            self._pkv = pkv
-        else:
-            st, tok, ln, rm, toks = self._decode_slots(
-                self.params, self._sstate, jnp.asarray(self._last),
-                jnp.asarray(self._lengths), jnp.asarray(self._rem), n=n)
-            self._sstate = st
-        t1b = time.perf_counter()      # carry uploads + launch: device idle
-        if self._fi is not None:       # chunk-sync fault sites
-            if self._fi.fire("chunk_latency"):
-                time.sleep(self._fi.latency_s("chunk_latency"))
-            if self._fi.fire("chunk_sync_exc"):
-                raise FaultInjected("chunk_sync_exc")
-            if self._fi.fire("crash_at"):
-                os._exit(137)          # hard mid-stream death, no cleanup
-        toks = np.asarray(toks)        # (B, n): the chunk's device sync
-        t2a = time.perf_counter()
-        # np.array (not asarray): device views are read-only and these
-        # mirrors are mutated by the next cycle's merge
-        self._last = np.array(tok)
-        self._lengths = np.array(ln)
-        self._rem = np.array(rm)
-        t2 = time.perf_counter()
-        emitted = 0
-        for b in np.nonzero(rem_before > 0)[0]:
-            k = int(min(n, rem_before[b]))
-            self._slot_out[b].extend(toks[b, :k].tolist())
-            emitted += k
-        with self._state_lock:
-            self.stats["decode_cycles"] += 1
-            self.stats["tokens_out"] += emitted
-        retire = self._collect_finished()
-        t3 = time.perf_counter()
-        self._note_rate(emitted, t3 - t0)
-        o = self.overlap_stats
-        o["cycles"] += 1
-        # dispatch_s here = mirror uploads + launch; under CPU contention
-        # the chunk starts computing mid-interval, so it is EXCLUDED from
-        # the gap (conservative: the true sync gap is larger)
-        o["dispatch_s"] += t1b - t1
-        o["wait_s"] += t2a - t1b
-        o["book_s"] += (t1 - t0) + (t2 - t2a) + (t3 - t2)
-        # sync-mode host gap: pre-work, the mirror download copies and all
-        # bookkeeping run with nothing queued on the device — the gap the
-        # async mode exists to close
-        o["gap_s"] += (t1 - t0) + (t2 - t2a) + (t3 - t2)
-        o["total_s"] += t3 - t0
-        chunk_s = t2a - t1             # upload + launch + block: the device
-        if o["min_chunk_s"] == 0.0 or chunk_s < o["min_chunk_s"]:
-            o["min_chunk_s"] = chunk_s  # cleanest (least contended) sample
-        if self._mh is not None:
-            mh = self._mh
-            mh["cycle"].record(t3 - t0)
-            mh["dispatch"].record(t1b - t1)
-            mh["sync"].record(t2a - t1b)
-            mh["book"].record((t1 - t0) + (t2 - t2a) + (t3 - t2))
-            mh["gap"].record((t1 - t0) + (t2 - t2a) + (t3 - t2))
-            mh["chunk"].record(chunk_s)
-            mh["tokens_out"].inc(emitted)
-        if self._tr is not None:
-            tr = self._tr
-            tr.add("cycle", TRACK_ENGINE, t0, t3, {"emitted": emitted})
-            tr.add("dispatch", TRACK_ENGINE, t1, t1b)
-            tr.add("sync", TRACK_ENGINE, t1b, t2a)
-            tr.add("bookkeeping", TRACK_ENGINE, t2a, t3)
+        with span("serve.dispatch", tr) as disp:  # carry upload + launch
+            if self.paged:
+                pkv, tok, ln, rm, toks = self._decode_paged(
+                    self.params, self._pkv, self._tables_dev,
+                    jnp.asarray(self._lengths), jnp.asarray(self._last),
+                    jnp.asarray(self._rem), n=n)
+                self._pkv = pkv
+            else:
+                st, tok, ln, rm, toks = self._decode_slots(
+                    self.params, self._sstate, jnp.asarray(self._last),
+                    jnp.asarray(self._lengths), jnp.asarray(self._rem), n=n)
+                self._sstate = st
+        with span("serve.sync", tr) as sync:
+            if self._fi is not None:       # chunk-sync fault sites
+                if self._fi.fire("chunk_latency"):
+                    time.sleep(self._fi.latency_s("chunk_latency"))
+                if self._fi.fire("chunk_sync_exc"):
+                    raise FaultInjected("chunk_sync_exc")
+                if self._fi.fire("crash_at"):
+                    os._exit(137)          # hard mid-stream death, no cleanup
+            toks = np.asarray(toks)        # (B, n): the chunk's device sync
+        with span("serve.bookkeeping", tr):
+            # np.array (not asarray): device views are read-only and these
+            # mirrors are mutated by the next cycle's merge
+            self._last = np.array(tok)
+            self._lengths = np.array(ln)
+            self._rem = np.array(rm)
+            emitted = 0
+            for b in np.nonzero(rem_before > 0)[0]:
+                k = int(min(n, rem_before[b]))
+                self._slot_out[b].extend(toks[b, :k].tolist())
+                emitted += k
+            with self._state_lock:
+                self.stats["decode_cycles"] += 1
+                self.stats["tokens_out"] += emitted
+            retire = self._collect_finished()
+        self._end_cycle(cyc, emitted, disp.seconds, sync.seconds)
         self._log("decode", pf.token, emitted)
         return ("cycle", (self._reset_epoch, retire))
 
-    def _st_decode_async(self, pf, msg):
+    def _st_decode_async(self, pf, msg, cyc):
         """Async decode lookahead (pipeline depth 2): dispatch chunk N+1
         FIRST — JAX async dispatch queues it behind the in-flight chunk N,
         so the device-side dependency chain never drains — then sync chunk
@@ -2479,46 +2458,45 @@ class ServeEngine:
         sequenced BEFORE the dispatch; retirement takes effect one chunk
         late (already masked on device by ``rem == 0``); a preempted row's
         in-flight tokens are discarded via the seat-generation guard."""
-        t0 = time.perf_counter()
+        tr = self._tr
         kind, payload = msg
         pend = self._pending
-        device_idle = (pend is None or bool(pend["toks"].is_ready())) \
-            and self._window_pending is None
         # ---- pre-dispatch: everything chunk N+1 must observe ----
         wpend, self._window_pending = self._window_pending, None
         if wpend is not None:
             self._finish_window(wpend)
         if kind == "admit":
-            if self.paged:
-                self._merge_group(pf, payload)
-            else:
-                self._merge_group_slots(pf, payload)
+            with span("serve.merge", tr):
+                if self.paged:
+                    self._merge_group(pf, payload)
+                else:
+                    self._merge_group_slots(pf, payload)
         self._sweep_seated(pf)
         if self.paged:
-            tg0 = time.perf_counter()
-            self._cow_guard(pf)
+            with span("serve.growth", tr):
+                self._cow_guard(pf)
             self._window_pending = self._dispatch_window_prefill(pf)
-            self._grow_or_preempt(pf)
-            if self._tr is not None:
-                self._tr.add("growth", TRACK_ENGINE, tg0,
-                             time.perf_counter())
+            with span("serve.growth", tr):
+                self._grow_or_preempt(pf)
         # ---- dispatch chunk N+1 (the device never waits on the host
         # bookkeeping below) ----
         n = self.decode_chunk
         new_pend = None
-        t1 = time.perf_counter()
+        dispatch_s = sync_s = 0.0
         if (self._rem > 0).any():
             rem_before = self._rem.copy()
-            if self.paged:
-                pkv, tok, ln, rm, toks = self._decode_paged(
-                    self.params, self._pkv, self._tables_dev,
-                    *self._carry, n=n)
-                self._pkv = pkv
-            else:
-                lengths, last, rem = self._carry
-                st, tok, ln, rm, toks = self._decode_slots(
-                    self.params, self._sstate, last, lengths, rem, n=n)
-                self._sstate = st
+            with span("serve.dispatch", tr) as disp:
+                if self.paged:
+                    pkv, tok, ln, rm, toks = self._decode_paged(
+                        self.params, self._pkv, self._tables_dev,
+                        *self._carry, n=n)
+                    self._pkv = pkv
+                else:
+                    lengths, last, rem = self._carry
+                    st, tok, ln, rm, toks = self._decode_slots(
+                        self.params, self._sstate, last, lengths, rem, n=n)
+                    self._sstate = st
+            dispatch_s = disp.seconds
             self._carry = (ln, tok, rm)
             # advance the host lengths/rem mirrors deterministically (the
             # chunk's length/rem arithmetic is token-independent); the
@@ -2532,68 +2510,40 @@ class ServeEngine:
             with self._state_lock:
                 self.stats["decode_cycles"] += 1
             self._log("dispatch", pf.token, int((rem_before > 0).sum()))
-        t2 = time.perf_counter()
         # ---- sync chunk N + host bookkeeping (overlaps N+1 on device) ----
         emitted = 0
-        wait_s = 0.0
         if pend is not None:
-            ts = time.perf_counter()
-            if self._fi is not None:   # chunk-sync fault sites
-                if self._fi.fire("chunk_latency"):
-                    time.sleep(self._fi.latency_s("chunk_latency"))
-                if self._fi.fire("chunk_sync_exc"):
-                    raise FaultInjected("chunk_sync_exc")
-                if self._fi.fire("crash_at"):
-                    os._exit(137)      # hard mid-stream death, no cleanup
-            toks = np.asarray(pend["toks"])
-            wait_s = time.perf_counter() - ts
-            for b in np.nonzero(pend["rem_before"] > 0)[0]:
-                if self._slot_gen[b] != pend["gen"][b]:
-                    continue    # seat changed since dispatch: surplus tokens
-                k = int(min(n, pend["rem_before"][b]))
-                self._slot_out[b].extend(toks[b, :k].tolist())
-                emitted += k
-            with self._state_lock:
-                self.stats["tokens_out"] += emitted
-            self._log("sync", pf.token, (pend["token"], emitted))
-        self._pending = new_pend
-        retire = self._collect_finished()
-        if self.paged and (pend is not None or (
-                new_pend is None and self._window_pending is None)):
-            # fence advance: a chunk was synced (or nothing is in flight
-            # at all) — blocks deferred two advances ago are now provably
-            # past every device write that could touch them
-            self._pool.release_deferred()
-        t3 = time.perf_counter()
-        self._note_rate(emitted, t3 - t0)
-        o = self.overlap_stats
-        o["cycles"] += 1
-        o["dispatch_s"] += t2 - t1
-        o["wait_s"] += wait_s
-        o["book_s"] += (t1 - t0) + (t3 - t2 - wait_s)
-        gap = 0.0
-        if device_idle:
-            gap += t1 - t0          # nothing in flight during pre-dispatch
-        if new_pend is None:
-            gap += t3 - t2 - wait_s  # nothing in flight during bookkeeping
-        o["gap_s"] += gap
-        o["total_s"] += t3 - t0
-        if self._mh is not None:
-            mh = self._mh
-            mh["cycle"].record(t3 - t0)
-            mh["dispatch"].record(t2 - t1)
-            mh["sync"].record(wait_s)
-            mh["book"].record((t1 - t0) + (t3 - t2 - wait_s))
-            mh["gap"].record(gap)
-            mh["tokens_out"].inc(emitted)
-        if self._tr is not None:
-            tr = self._tr
-            tr.add("cycle", TRACK_ENGINE, t0, t3, {"emitted": emitted})
-            if new_pend is not None:
-                tr.add("dispatch", TRACK_ENGINE, t1, t2)
+            with span("serve.sync", tr) as sync:
+                if self._fi is not None:   # chunk-sync fault sites
+                    if self._fi.fire("chunk_latency"):
+                        time.sleep(self._fi.latency_s("chunk_latency"))
+                    if self._fi.fire("chunk_sync_exc"):
+                        raise FaultInjected("chunk_sync_exc")
+                    if self._fi.fire("crash_at"):
+                        os._exit(137)  # hard mid-stream death, no cleanup
+                toks = np.asarray(pend["toks"])
+            sync_s = sync.seconds
+        with span("serve.bookkeeping", tr):
             if pend is not None:
-                tr.add("sync", TRACK_ENGINE, ts, ts + wait_s)
-            tr.add("bookkeeping", TRACK_ENGINE, t2, t3)
+                for b in np.nonzero(pend["rem_before"] > 0)[0]:
+                    if self._slot_gen[b] != pend["gen"][b]:
+                        continue    # seat changed since dispatch: surplus
+                    k = int(min(n, pend["rem_before"][b]))
+                    self._slot_out[b].extend(toks[b, :k].tolist())
+                    emitted += k
+                with self._state_lock:
+                    self.stats["tokens_out"] += emitted
+                self._log("sync", pf.token, (pend["token"], emitted))
+            self._pending = new_pend
+            retire = self._collect_finished()
+            if self.paged and (pend is not None or (
+                    new_pend is None and self._window_pending is None)):
+                # fence advance: a chunk was synced (or nothing is in
+                # flight at all) — blocks deferred two advances ago are
+                # now provably past every device write that could touch
+                # them
+                self._pool.release_deferred()
+        self._end_cycle(cyc, emitted, dispatch_s, sync_s)
         self._log("decode", pf.token, emitted)
         return ("cycle", (self._reset_epoch, retire))
 

@@ -19,7 +19,7 @@ import pytest
 from repro.obs import (TRACK_ENGINE, Counter, Gauge, Histogram,
                        MetricsRegistry, Observability, StatsLogger, Tracer,
                        chrome_trace_events, env_enabled, export_chrome_trace,
-                       from_env)
+                       from_env, span)
 
 
 # ------------------------------------------------------------------ tracer
@@ -39,7 +39,7 @@ def test_tracer_disabled_records_nothing():
     tr = Tracer(enabled=False)
     tr.add("a", "t", 0.0, 1.0)
     tr.instant("b", "t")
-    with tr.span("c", "t"):
+    with span("c", tr, track="t"):
         pass
     assert len(tr) == 0
     tr.enabled = True           # re-checked per call
@@ -49,7 +49,7 @@ def test_tracer_disabled_records_nothing():
 
 def test_tracer_span_context_and_instant():
     tr = Tracer()
-    with tr.span("work", "t", {"k": 1}):
+    with span("work", tr, track="t", k=1):
         pass
     tr.instant("mark", "t")
     spans = tr.spans()
@@ -302,22 +302,21 @@ def test_engine_lifecycle_spans_and_metric_consistency(setup, tmp_path):
             assert te >= ts
             by_name.setdefault(name, []).append((track, ts, te, args))
         for required in ("queued", "admitted", "decode", "retired",
-                        "admission", "cycle"):
+                         "serve.admission", "serve.cycle"):
             assert required in by_name, f"missing {required} spans"
         # one queued->admitted chain and one retired instant per request
         for evt in ("queued", "admitted", "retired"):
             got = sorted(a["req"] for _, _, _, a in by_name[evt])
             assert got == sorted(r.id for r in reqs)
-        assert all(t == TRACK_ENGINE for t, _, _, _ in by_name["cycle"])
-        # "decode" spans live on BOTH slot tracks (request lifecycle) and
-        # line tracks (the decode PIPE body) — the lifecycle ones are the
-        # slot-track subset
-        slot_decode = [(t, ts, te) for t, ts, te, _ in by_name["decode"]
-                       if t.startswith("slot")]
-        line_tracks = {t for t, _, _, _ in by_name["decode"]
-                       if not t.startswith("slot")}
+        assert all(t == TRACK_ENGINE
+                   for t, _, _, _ in by_name["serve.cycle"])
+        # "decode" spans are the request lifecycle on slot tracks; the
+        # decode PIPE body is "pipe.decode" on line tracks
+        slot_decode = [(t, ts, te) for t, ts, te, _ in by_name["decode"]]
         assert slot_decode
-        assert all(t.startswith("line") for t in line_tracks)
+        assert all(t.startswith("slot") for t, _, _ in slot_decode)
+        assert all(t.startswith("line")
+                   for t, _, _, _ in by_name["pipe.decode"])
 
         # ---- acceptance: per-slot decode span time bounded by wall time
         per_slot = {}
@@ -332,7 +331,8 @@ def test_engine_lifecycle_spans_and_metric_consistency(setup, tmp_path):
         assert snap["serve.requests.admitted"] == len(reqs)
         assert snap["serve.requests.retired"] == len(reqs)
         assert snap["serve.tokens_out"] == eng.stats["tokens_out"]
-        assert snap["engine.cycle_s"]["count"] == len(by_name["cycle"])
+        assert snap["engine.cycle_s"]["count"] == \
+            len(by_name["serve.cycle"])
         # drained: gauges back to idle
         assert snap["serve.queue_depth"] == 0
         assert snap["serve.resident_rows"] == 0

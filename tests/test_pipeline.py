@@ -453,7 +453,7 @@ def test_stage_times_promote_to_tracer_spans(executor):
     # 4 tokens x 2 stages + the stopping admit visit
     assert len(spans) == 4 * 2 + 1
     assert {s[1] for s in spans} == {"line0", "line1"}
-    assert {s[0] for s in spans} == {"admit", "work"}
+    assert {s[0] for s in spans} == {"pipe.admit", "pipe.work"}
     assert all(s[3] >= s[2] for s in spans)
     # span sum == stage_times aggregate (same measurements, two views)
     agg = sum(s[3] - s[2] for s in spans)
