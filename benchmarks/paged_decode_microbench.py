@@ -18,6 +18,13 @@ bound their work by ``max(lengths)``, so their cost follows *occupancy*:
   lowering on TPU is the perf path).
 * ``decode_step_*`` — end-to-end ``lm.decode_step_paged`` (all layers,
   projections, MLP) at low occupancy, paged vs gather read path.
+
+``--chip`` times, on a TPU only, the Mosaic-compiled Pallas kernel against
+the XLA page loop per layer call at the chip benchmark cells' attention
+shapes (``CHIP_SHAPES``): 8 rows, a 128-column table of 16-token pages,
+bf16 pages, rows spread around the cell traffic's mean live length::
+
+    PYTHONPATH=src python benchmarks/paged_decode_microbench.py --chip
 """
 from __future__ import annotations
 
@@ -106,6 +113,93 @@ def bench(quick: bool = False) -> Iterator[Tuple[str, str, str]]:
     yield ("decode_step_gather_low_occ_ms", f"{times['gather']:.3f}", "")
 
 
+#: the chip cells' decode attention: (config, query heads, KV heads,
+#: head_dim, mean live length of the cell's traffic in tokens)
+CHIP_SHAPES = (("stablelm-1.6b", 32, 32, 64, 230),
+               ("mistral-nemo-12b", 32, 8, 128, 550))
+CHIP_B, CHIP_BLOCK, CHIP_MAX_BLOCKS = 8, 16, 128
+#: layers in the timed pool and calls per timed program (layer i % L)
+CHIP_LAYERS, CHIP_CALLS = 4, 24
+
+
+def chip_case(H: int, KV: int, hd: int, mean_len: int, seed: int = 0):
+    """Inputs of one layer call at a chip shape: bf16 queries and pool,
+    every row's pages scattered over the pool, row lengths spread evenly
+    from half to one and a half times ``mean_len``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.paged_attention import kv_pack
+
+    B, bs, mb = CHIP_B, CHIP_BLOCK, CHIP_MAX_BLOCKS
+    P = kv_pack(KV, hd)
+    N = B * mb + 1
+    kq, kp = jax.random.split(jax.random.PRNGKey(seed))
+    q = jax.random.normal(kq, (B, H, hd), jnp.bfloat16)
+    pool = jax.random.normal(
+        kp, (N, CHIP_LAYERS, 2, KV // P, bs, P * hd), jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+    tables = jnp.asarray(
+        1 + rng.permutation(B * mb).astype(np.int32).reshape(B, mb))
+    lengths = jnp.asarray(
+        np.rint(mean_len * np.linspace(0.5, 1.5, B)).astype(np.int32))
+    return q, pool, tables, lengths
+
+
+def time_layer_calls(attn, q, pool, tables, lengths, reps: int = 20):
+    """Time per layer call of ``attn(q, pool, tables, lengths, layer)``
+    in ms: host clock around one program of ``CHIP_CALLS`` chained calls
+    (each output is the next query) up to ``block_until_ready``, so one
+    dispatch spreads over them; median of ``reps``."""
+    import statistics
+    import jax
+
+    @jax.jit
+    def chain(q, pool, tables, lengths):
+        return jax.lax.fori_loop(
+            0, CHIP_CALLS,
+            lambda i, x: attn(x, pool, tables, lengths, i % CHIP_LAYERS), q)
+
+    args = (q, pool, tables, lengths)       # arguments, not constants
+    chain(*args).block_until_ready()        # compile and warm up
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        chain(*args).block_until_ready()
+        times.append((time.perf_counter() - t0) * 1e3 / CHIP_CALLS)
+    return statistics.median(times)
+
+
+def chip_bench(reps: int = 20) -> Iterator[Tuple[str, str, str]]:
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.ops import on_tpu
+    from repro.kernels.paged_attention import paged_attention
+
+    if not on_tpu():
+        raise SystemExit("--chip times the Mosaic kernel: it needs a TPU")
+    dev = jax.devices()[0]
+    yield ("device", dev.device_kind, f"{jax.device_count()}_devices")
+    for name, H, KV, hd, mean_len in CHIP_SHAPES:
+        q, pool, tables, lengths = chip_case(H, KV, hd, mean_len)
+        impls = {
+            impl: functools.partial(paged_attention, impl=impl,
+                                    interpret=False)
+            for impl in ("pallas", "xla")}
+        out = {impl: f(q, pool, tables, lengths, 0).astype(jnp.float32)
+               for impl, f in impls.items()}
+        gap = float(jnp.max(jnp.abs(out["pallas"] - out["xla"])))
+        t = {impl: time_layer_calls(f, q, pool, tables, lengths, reps)
+             for impl, f in impls.items()}
+        yield (f"chip_{name}_len{mean_len}_pallas_ms", f"{t['pallas']:.5f}",
+               f"{t['xla'] / t['pallas']:.2f}x_xla")
+        yield (f"chip_{name}_len{mean_len}_xla_ms", f"{t['xla']:.5f}", "")
+        yield (f"chip_{name}_len{mean_len}_max_abs_diff", f"{gap:.3g}",
+               "pallas_vs_xla")
+
+
 if __name__ == "__main__":
-    for name, val, derived in bench(quick=True):
-        print(f"{name},{val},{derived}")
+    import sys
+    rows = chip_bench() if "--chip" in sys.argv[1:] else bench(quick=True)
+    for name, val, derived in rows:
+        print(f"{name},{val},{derived}", flush=True)

@@ -3,7 +3,11 @@
 The Pallas kernel (interpret mode) and the traced-bound XLA page loop must
 reproduce the materialize-then-mask reference (``kvcache.gather_pages`` +
 masked softmax) across GQA ratios, ragged per-row lengths, rows parked on
-the sink block, and block sizes that do not divide ``pos + 1``. Also the
+the sink block, and block sizes that do not divide ``pos + 1``. The kernel
+fetches a row's pages in groups; ``pallas/K`` runs it with ``K`` pages a
+group, so lengths land on and around group edges, rows span several
+groups and tables end inside a group. A structural guard keeps its grid
+at one step per row. Also the
 causal block-pruning parity for the prefill flash kernel: skipping
 fully-above-diagonal kv blocks is bit-identical to masking them.
 """
@@ -14,7 +18,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.ops import flash_attention, paged_attention
-from repro.kernels.paged_attention import kv_pack
+from repro.kernels.paged_attention import _paged_attention_pallas, kv_pack
 from repro.models.attention import init_attention, paged_decode_attention
 from repro.serve.kvcache import gather_read_attention as _gather_oracle
 
@@ -48,21 +52,37 @@ def _make_case(B, H, KV, hd, bs, mb, lengths, seed=0, dtype=jnp.float32,
     return q, pool_kv, jnp.asarray(tables), jnp.asarray(lengths)
 
 
-@pytest.mark.parametrize("impl", ["pallas", "xla"])
+_grouped = jax.jit(_paged_attention_pallas,
+                   static_argnames=("interpret", "group"))
+
+
+def _attend(impl, q, pool_kv, tables, lengths, layer):
+    """``pallas`` and ``xla`` as the engine calls them; ``pallas/K`` is
+    the kernel in interpret mode with ``K`` pages a DMA group (its own
+    choice, from the shapes, fits a whole small table in one group)."""
+    if impl.startswith("pallas/"):
+        return _grouped(q, pool_kv, tables, lengths, layer, interpret=True,
+                        group=int(impl.split("/")[1]))
+    return paged_attention(q, pool_kv, tables, lengths, layer, impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "pallas/2", "pallas/4"])
 @pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (8, 1)])
 def test_paged_matches_gather_across_gqa_and_ragged_lengths(impl, H, KV):
     B, hd, bs, mb = 5, 32, 16, 6
     # ragged: empty row (pos=0), mid-block, exact block boundary (bs does
-    # not divide pos+1 except row 3), near-capacity
+    # not divide pos+1 except row 3), near-capacity. With 2 pages a group
+    # row 3 opens a second group with one page and row 4 fills three; with
+    # 4, the 6-column table ends inside the second group
     lengths = [0, 7, bs - 1, 2 * bs, mb * bs - 1]
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, lengths)
-    for layer in range(pool_kv.shape[0]):
-        out = paged_attention(q, pool_kv, tables, ln, layer, impl=impl)
+    for layer in range(pool_kv.shape[1]):
+        out = _attend(impl, q, pool_kv, tables, ln, layer)
         ref = _gather_oracle(q, pool_kv, layer, tables, ln)
         assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
 
-@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("impl", ["pallas", "xla", "pallas/2"])
 def test_paged_inactive_sink_rows(impl):
     """Rows parked on the sink block (every table entry 0) stay finite and
     match the oracle; live rows are untouched by their presence."""
@@ -70,20 +90,73 @@ def test_paged_inactive_sink_rows(impl):
     lengths = [5, -1, 20, -1]              # rows 1 and 3 are sink-parked
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, lengths)
     assert int(tables[1].sum()) == 0 and int(tables[3].sum()) == 0
-    out = paged_attention(q, pool_kv, tables, ln, 1, impl=impl)
+    out = _attend(impl, q, pool_kv, tables, ln, 1)
     ref = _gather_oracle(q, pool_kv, 1, tables, ln)
     assert bool(jnp.all(jnp.isfinite(out)))
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
 
 
-@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("impl", ["pallas", "xla", "pallas/3"])
 @pytest.mark.parametrize("bs,pos", [(4, 4), (4, 10), (3, 7), (5, 5)])
 def test_paged_block_size_not_dividing_pos(impl, bs, pos):
     B, H, KV, hd, mb = 2, 4, 2, 16, 4
     q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, [pos, pos % bs])
-    out = paged_attention(q, pool_kv, tables, ln, 2, impl=impl)
+    out = _attend(impl, q, pool_kv, tables, ln, 2)
     ref = _gather_oracle(q, pool_kv, 2, tables, ln)
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
+
+
+#: the chip cells' packings: MHA at hd 64, two heads a row (P = 2, G = 1);
+#: GQA at hd 128, one head a row, four queries a KV head (P = 1, G = 4)
+PACKINGS = {"mha-hd64-P2": (4, 4, 64), "gqa-hd128-G4": (8, 2, 128)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("packing", sorted(PACKINGS))
+@pytest.mark.parametrize("K", [2, 3])
+@pytest.mark.parametrize("rows", ["edges", "one-row"])
+def test_paged_group_edges(rows, K, packing, dtype):
+    """Lengths ending just before, on and just after a group edge, a row
+    over four groups, a sink-parked row and an empty one, in a table of
+    ``3K + 1`` columns (not a multiple of ``K``); or a single row filling
+    the whole table. Against the oracle in float32 on the same values."""
+    H, KV, hd = PACKINGS[packing]
+    bs, mb = 8, 3 * K + 1
+    lengths = {"edges": [K * bs - 1, K * bs, K * bs + 1, mb * bs - 1, -1, 0],
+               "one-row": [mb * bs - 1]}[rows]
+    q, pool_kv, tables, ln = _make_case(len(lengths), H, KV, hd, bs, mb,
+                                        lengths, dtype=jnp.dtype(dtype), L=2)
+    assert kv_pack(KV, hd) == (2 if hd == 64 else 1)
+    out = _attend(f"pallas/{K}", q, pool_kv, tables, ln, 1)
+    assert out.dtype == q.dtype
+    ref = _gather_oracle(q.astype(jnp.float32), pool_kv.astype(jnp.float32),
+                         1, tables, ln)
+    tol = 2e-5 if dtype == "float32" else 2.0 ** -8 * float(
+        jnp.max(jnp.abs(ref)))
+    assert jnp.max(jnp.abs(out.astype(jnp.float32) - ref)) < tol
+
+
+def _pallas_grids(jaxpr):
+    """Grids of every ``pallas_call`` in ``jaxpr``, nested ones included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("mb", [8, 64])
+def test_paged_kernel_grid_is_one_step_per_row(mb):
+    """The grid is ``(B,)`` whatever the table width and the number of
+    packed KV rows: dead pages get no grid step."""
+    B, H, KV, hd, bs = 3, 8, 8, 64, 16
+    q, pool_kv, tables, ln = _make_case(B, H, KV, hd, bs, mb, [5, 40, 100],
+                                        L=1)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_attention(*a, impl="pallas"))(
+        q, pool_kv, tables, ln, 0)
+    assert _pallas_grids(jaxpr.jaxpr) == [(B,)]
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])

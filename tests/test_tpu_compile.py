@@ -5,7 +5,11 @@ The TPU compiler is installed with JAX and compiles for a chip described by
 own verdicts on the main path at published widths:
 
 * the Pallas paged decode kernel lowers through Mosaic (``interpret=False``)
-  at stablelm-1.6b's widths and at a GQA config's;
+  at stablelm-1.6b's widths and at two GQA configs' (mistral-nemo-12b's is
+  a chip benchmark cell's);
+* in the decode chunk the kernel's custom call keeps the HLO name
+  ``paged_attention``, which the chip benchmark's trace reader
+  (``benchmarks/chip/tracereduce.op_name``) matches;
 * the decode chunk and the chunked-prefill window, with bf16 weights and a
   donated 4 GiB pool, update the pool in place: temporaries stay under
   1 GiB and arguments plus temporaries fit the chip's 15.75 GiB.
@@ -16,6 +20,8 @@ so every test worker collects the same tests and only the worker given this
 file loads the TPU library.
 """
 import dataclasses
+import importlib.util
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -66,13 +72,6 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture
-def mosaic(monkeypatch):
-    """The program asks the platform which kernel mode to use; here the
-    platform is the CPU, so tell it the chip is there."""
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-
-
 def _spec(tree, sharding):
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
@@ -95,7 +94,8 @@ def stablelm(one_chip):
     return cfg, params, pool
 
 
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "qwen3-14b"])
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "qwen3-14b",
+                                  "mistral-nemo-12b"])
 def test_paged_kernel_lowers_through_mosaic(arch, one_chip,
                                             no_persistent_cache):
     cfg = get_config(arch)
@@ -119,17 +119,45 @@ def _assert_in_place(compiled):
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < HBM_BYTES
 
 
-def test_decode_chunk_updates_pool_in_place(stablelm, one_chip, mosaic,
-                                            no_persistent_cache):
+@pytest.fixture(scope="module")
+def decode_chunk(stablelm, one_chip, no_persistent_cache):
+    """The 8-step decode chunk at stablelm's widths, compiled with the
+    Mosaic kernel (the program asks the platform which kernel mode to use;
+    here the platform is the CPU, so it is told the chip is there)."""
     cfg, params, pool = stablelm
     fn = jax.jit(lambda p, pkv, t, ln, last, rem: lm.decode_chunk_paged(
         cfg, p, pkv, t, (ln, last, rem), 8, impl="pallas"),
         donate_argnums=(1,))
-    compiled = fn.lower(params, pool, _i32(one_chip, B, MAX_BLOCKS),
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        return fn.lower(params, pool, _i32(one_chip, B, MAX_BLOCKS),
                         _i32(one_chip, B), _i32(one_chip, B),
                         _i32(one_chip, B)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _assert_in_place(compiled)
+
+
+def test_decode_chunk_updates_pool_in_place(decode_chunk):
+    assert "tpu_custom_call" in decode_chunk.as_text()
+    _assert_in_place(decode_chunk)
+
+
+def _trace_op_name():
+    """``op_name`` of the chip benchmark's trace reader, loaded by path."""
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "tracereduce.py")
+    spec = importlib.util.spec_from_file_location("_tracereduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.op_name
+
+
+def test_decode_chunk_kernel_keeps_its_trace_name(decode_chunk):
+    """``paged_decode_roofline`` sums the device time of ops named
+    ``paged_attention``: the kernel's custom call must keep that name."""
+    op_name = _trace_op_name()
+    names = {op_name(line.strip()) for line in
+             decode_chunk.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert "paged_attention" in names, names
 
 
 def test_prefill_window_updates_pool_in_place(stablelm, one_chip,
