@@ -45,9 +45,21 @@ def tiny_config(max_logit_gap: float = 0.01) -> dict:
     return conf
 
 
-def add_cell(root: Path, config: str, conf: dict, mixes: dict) -> None:
-    """Add a configuration, its traffic mixes and one cell per mix to the
-    checkout at ``root``: new files and appended manifest entries only."""
+def tiny_mesh_config() -> dict:
+    """The smoke-size configuration on a ``model=4`` mesh: 8 query and 4
+    KV heads, so that four devices each hold one KV head."""
+    conf = tiny_config()
+    conf.update(num_attention_heads=8, num_key_value_heads=4)
+    conf["program"]["fields"].update(num_heads=8, num_kv_heads=4)
+    conf["mesh"] = {"data": 1, "model": 4}
+    return conf
+
+
+def add_cell(root: Path, config: str, conf: dict, mixes: dict,
+             chips: int = 1) -> None:
+    """Add a configuration, its traffic mixes and one cell per mix on
+    ``chips`` chips to the checkout at ``root``: new files and appended
+    manifest entries only."""
     bench = root / "benchmarks" / "chip"
     (bench / "configs" / f"{config}.json").write_text(json.dumps(conf))
     man = json.loads((root / "BENCHMARK.json").read_text())
@@ -57,7 +69,7 @@ def add_cell(root: Path, config: str, conf: dict, mixes: dict) -> None:
     for mix, spec in mixes.items():
         (bench / "traffic" / f"{mix}.json").write_text(json.dumps(spec))
         man["workloads"].append({"name": f"{config}.{mix}", "config": config,
-                                 "traffic": mix, "chips": 1,
+                                 "traffic": mix, "chips": chips,
                                  "why": "CPU tests"})
     (root / "BENCHMARK.json").write_text(json.dumps(man))
 

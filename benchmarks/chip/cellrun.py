@@ -7,24 +7,29 @@ and entries; nothing here changes.
 
 The system under test is ``repro.serve.engine.ServeEngine``, built with
 the configuration file's ``engine`` options only (the options a
-deployment's operator sets). Every other choice — kernels, chunk sizes,
-overlap, prefix caching — is the program's own default, so a PR that
-changes a default is measured.
+deployment's operator sets), and, where the file carries a ``mesh``
+(``{"data": 1, "model": 4}``), on that tensor-parallel mesh over the
+cell's own chips. Every other choice — kernels, chunk sizes, overlap,
+prefix caching — is the program's own default, so a PR that changes a
+default is measured.
 
 A run:
 
 1. enables the persistent compile cache inside the checkout, and fails
    with no result when JAX finds no TPU, too few chips, or a chip kind
    missing from ``peaks.json``;
-2. makes bf16 weights on the device from ``--seed`` in one jitted call;
+2. makes bf16 weights on the device from ``--seed`` in one jitted call,
+   each leaf born on its shard where the file names a mesh;
 3. builds one engine and warms every prompt-window shape the traffic can
    use, by sending one request at each power-of-two boundary of the
    mix's prompt range, each admitted alone;
 4. starts the traffic: a closed loop opens the window once every client
    has had a request returned, an open loop after its lead-in;
-5. measures ``--seconds`` seconds (with ``--trace 1`` under the
-   profiler), then stops sending, waits for every request still out,
-   reads the device's peak memory and frees the engine;
+5. measures ``--seconds`` seconds (with ``--trace 1`` the last
+   ``trace_s`` of them under the profiler, where the configuration file
+   gives ``trace_s``, else all of them), then stops sending, waits for
+   every request still out, reads the device's peak memory and frees
+   the engine;
 6. checks what the window served against the float32 reference
    (``check.py``) and prints one JSON line.
 """
@@ -57,6 +62,8 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
 DRAIN_S = 150.0
 #: requests queued per closed-loop client (far more than a window uses)
 PER_CLIENT = 64
+#: seconds the profiler starts ahead of the window
+TRACE_LEAD_S = 2.0
 
 
 class NoChip(Exception):
@@ -87,6 +94,8 @@ def load_cell(root: Path, workload: str) -> Dict[str, Any]:
     w = cells[workload]
     confs = {c["name"]: c for c in man["configs"]}
     conf = _read_json(root / confs[w["config"]]["file"])
+    if not conf.get("trace_s", 1) > 0:
+        raise CellError(f"trace_s {conf['trace_s']!r}: give a length > 0")
     mix = _read_json(bench / "traffic" / f"{w['traffic']}.json")
 
     # every end-to-end metric is every cell's; a per-layer metric lists
@@ -142,11 +151,39 @@ def build_config(conf: Dict):
     return cfg
 
 
-def make_weights(cfg, seed: int):
+def mesh_ctx(conf: Dict, devs: List):
+    """The serve ``ShardCtx`` over the cell's own devices that the file's
+    ``mesh`` names (``{"data": d, "model": m}``), or None without one."""
+    mesh = conf.get("mesh")
+    if mesh is None:
+        return None
+    if set(mesh) != {"data", "model"}:
+        raise CellError(f"mesh {mesh}: give its data and model axes")
+    shape = (int(mesh["data"]), int(mesh["model"]))
+    if shape[0] * shape[1] != len(devs):
+        raise CellError(f"mesh {mesh} spans {shape[0] * shape[1]} devices; "
+                        f"the cell has {len(devs)} chips")
+    import jax
+    from repro.launch.mesh import make_ctx
+    auto = (jax.sharding.AxisType.Auto,) * 2
+    return make_ctx(jax.make_mesh(shape, ("data", "model"), axis_types=auto,
+                                  devices=devs))
+
+
+def make_weights(cfg, seed: int, ctx=None):
+    """The program's weights from ``seed``; with a mesh ``ctx``, each leaf
+    is made on its shard as the engine places it, so no device ever holds
+    the whole model."""
     import jax
     from repro.models import lm
-    params = jax.jit(functools.partial(lm.init_params, cfg))(
-        jax.random.PRNGKey(seed))
+    init = functools.partial(lm.init_params, cfg)
+    key = jax.random.PRNGKey(seed)
+    if ctx is None:
+        params = jax.jit(init)(key)
+    else:
+        from repro.distributed.sharding import serve_param_shardings
+        shard = serve_param_shardings(cfg, jax.eval_shape(init, key), ctx)
+        params = jax.jit(init, out_shardings=shard)(key)
     return jax.block_until_ready(params)
 
 
@@ -177,6 +214,10 @@ def warm_up(eng, mix: Dict, vocab: int, seed: int) -> int:
     for r in reqs:
         eng.result(r, timeout=900.0)
     return len(reqs)
+
+
+def _wait_until(t: float) -> None:
+    time.sleep(max(0.0, t - time.perf_counter()))
 
 
 # ---------------------------------------------------------------- traffic
@@ -365,6 +406,8 @@ def run(root: Path, workload: str, seed: int, seconds: int, trace: bool,
     t_start = time.perf_counter() if t_start is None else t_start
     cell = load_cell(root, workload)
     import jax
+    # arrays that predate the run are not its to free
+    before = jax.live_arrays()
     from repro.launch.compile_cache import enable_compile_cache
     if need_chip:
         log(f"compile cache: {enable_compile_cache()}")
@@ -384,10 +427,12 @@ def run(root: Path, workload: str, seed: int, seconds: int, trace: bool,
 
     conf, mix = cell["config"], cell["mix"]
     cfg = build_config(conf)
-    params = make_weights(cfg, seed)
-    eng = ServeEngine(cfg, params, **conf["engine"])
+    ctx = mesh_ctx(conf, devs)
+    params = make_weights(cfg, seed, ctx)
+    eng = ServeEngine(cfg, params, ctx=ctx, **conf["engine"])
     log(f"{cell['name']}: {cfg.name} {cfg.num_layers} layers d{cfg.d_model}"
-        f" on {devs[0].device_kind} x {len(devs)}; engine "
+        f" on {devs[0].device_kind} x {len(devs)}, mesh "
+        f"{conf.get('mesh', 'none')}; engine "
         f"paged_impl={eng.paged_impl} decode_chunk={eng.decode_chunk} "
         f"prefill_chunk={eng.prefill_chunk}")
     n_warm = warm_up(eng, mix, cfg.vocab_size, seed)
@@ -398,42 +443,61 @@ def run(root: Path, workload: str, seed: int, seconds: int, trace: bool,
     t_open = loop.start()
 
     # ---- the window
+    # a --trace 1 run traces the window's last ``trace_s`` seconds (the
+    # configuration's, or the whole window), the profiler started just
+    # ahead: a trace's size, and the time to write and read it, grow with
+    # its length, chips and layers
+    t_trace = t_open + seconds - min(seconds, conf.get("trace_s", seconds))
+    early = t_trace - TRACE_LEAD_S <= t_open
     prof = None
     if trace:
         prof = tracereduce.Profile(cell["bench"] / ".traces" / cell["name"])
-        prof.start()
-    time.sleep(max(0.0, t_open - time.perf_counter()))
+        if early:
+            _wait_until(t_trace - TRACE_LEAD_S)
+            prof.start()
+    _wait_until(t_open)
     counting.set()
-    if prof is not None:
-        prof.open_window()
     t_open = max(t_open, time.perf_counter())
     tok_open = eng.stats["tokens_out"]
     setup_s = t_open - t_start
-    time.sleep(max(0.0, t_open + seconds - time.perf_counter()))
+    if trace:
+        if not early:
+            _wait_until(t_trace - TRACE_LEAD_S)
+            prof.start()
+        _wait_until(t_trace)
+        prof.open_window()
+        t_trace = max(t_trace, time.perf_counter())
+        tok_trace = eng.stats["tokens_out"]
+    _wait_until(t_open + seconds)
     tok_close = eng.stats["tokens_out"]
     t_close = time.perf_counter()
     if prof is not None:
         prof.stop()
+        log(f"traced the window's last {t_close - t_trace:.3f} s; trace "
+            f"written in {time.perf_counter() - t_close:.3f} s")
     counting.clear()
     n_compiles = sum(compiles.values())
 
     # ---- drain, then free the program's state
     stuck = loop.close()
     stats = dict(eng.stats)
-    peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                     for d in devs)
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in devs]
+    peak_bytes = max(peaks)
+    log(f"peak bytes in use per chip: {peaks}")
     wm = window_metrics(loop.records, t_open, t_close, tok_open, tok_close)
     eng.close(timeout=30.0)
     decode_chunk = eng.decode_chunk
     loop.eng = None
     del eng, params
     gc.collect()
-    left = jax.live_arrays()
+    kept = {id(a) for a in before}
+    left = [a for a in jax.live_arrays() if id(a) not in kept]
     log(f"program state freed; {len(left)} arrays, "
         f"{sum(a.nbytes for a in left)} bytes left on the device, deleted")
     for a in left:
         a.delete()
-    del left
+    del left, before
 
     log(f"setup {setup_s:.3f} s ({n_warm} warm-up requests); window "
         f"{wm['window_s']:.3f} s; requests due in window {wm['n_due']}, "
@@ -473,16 +537,18 @@ def run(root: Path, workload: str, seed: int, seconds: int, trace: bool,
             out["metrics"][m["name"]] = {
                 "value": v if math.isfinite(v) else None, "unit": m["unit"]}
     else:
-        rec = tracereduce.records_from_file(prof.path())
+        t_read = time.perf_counter()
+        rec = tracereduce.records_from_file(prof.path(), len(devs))
+        log(f"trace read in {time.perf_counter() - t_read:.3f} s")
         rec.update({
-            "requests": request_rows(loop.records, t_open),
-            "window_s": t_close - t_open, "window_metrics": wm,
+            "requests": request_rows(loop.records, t_trace),
+            "window_s": t_close - t_trace, "window_metrics": wm,
             "memory_peak_bytes": int(peak_bytes), "peak": peak,
             "decode_chunk": decode_chunk,
             "block_size": int(conf["engine"]["block_size"]),
             "model": conf,
         })
-        rec["counters"] = {"tokens_out": tok_close - tok_open}
+        rec["counters"] = {"tokens_out": tok_close - tok_trace}
         if save_records:
             tracereduce.save_records(rec, save_records)
             shutil.copy(prof.path(), save_records + ".xplane.pb")

@@ -46,10 +46,18 @@ def test_manifest_names_files_that_exist():
 
 
 def test_every_cell_reads_its_metrics():
-    for w in _manifest()["workloads"]:
+    man = _manifest()
+    for w in man["workloads"]:
         cell = cellrun.load_cell(REPO, w["name"])
         names = [m["name"] for m in cell["per_layer"]]
-        assert len(names) == 7, names
+        listed = [m["name"] for m in man["per_layer"]
+                  if w["name"] in m["workloads"]]
+        assert names == listed and names, (w["name"], names)
+        for name in names:
+            assert (HERE / "metrics" / f"{name}.py").is_file(), name
+    cells = {w["name"] for w in man["workloads"]}
+    for m in man["per_layer"]:
+        assert set(m["workloads"]) <= cells, m["name"]
 
 
 @pytest.mark.parametrize("mix", ["decode", "chat"])
@@ -194,3 +202,40 @@ def test_smoke_cell_runs_correct(tiny_root, cell):
     gap = out["checks"]["logit_gap"]
     assert gap["value"] <= gap["limit"]
     assert np.isfinite(out["metrics"]["ttft_p90_s"]["value"])
+
+
+def test_traced_run_traces_the_last_trace_s(tmp_path):
+    """A configuration's ``trace_s`` shortens the traced part of the
+    window to its last seconds; the window itself keeps its length."""
+    root = copy_checkout(tmp_path)
+    add_cell(root, "tinyshort", dict(tiny_config(), trace_s=1),
+             {"tinyopen": TINY_MIXES["tinyopen"]})
+    logs = []
+    out = cellrun.run(root, "tinyshort.tinyopen", BIG_SEED, 3, trace=True,
+                      need_chip=False, log=logs.append)
+    assert out["correct"] is True
+    assert out["device"]["window_s"] == pytest.approx(1.0, abs=0.1)
+    line, = [m for m in logs if m.startswith("traced the window's last ")]
+    traced = float(line.split()[4])
+    assert traced == pytest.approx(1.0, abs=0.1)
+    assert any(m.startswith("setup ") and "; window 3.0" in m for m in logs)
+
+
+def test_trace_s_must_be_positive(tmp_path):
+    root = copy_checkout(tmp_path, with_src=False)
+    add_cell(root, "tinybad", dict(tiny_config(), trace_s=0),
+             {"tinyopen": TINY_MIXES["tinyopen"]})
+    with pytest.raises(cellrun.CellError, match="trace_s 0"):
+        cellrun.load_cell(root, "tinybad.tinyopen")
+
+
+def test_run_frees_only_its_own_arrays(tiny_root):
+    """A run deletes what it left on the device before the reference
+    runs, and no array that was live before it started."""
+    import jax.numpy as jnp
+    mine = jnp.arange(7)
+    out = cellrun.run(tiny_root, "tiny.tinyclosed", BIG_SEED, 1,
+                      trace=False, need_chip=False, log=lambda *_: None)
+    assert out["correct"] is True
+    assert not mine.is_deleted()
+    assert int(mine.sum()) == 21

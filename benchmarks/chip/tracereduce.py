@@ -6,19 +6,28 @@ are on the trace's own clock. ``records_from_file`` reads the trace back
 (``jax.profiler.ProfileData``, nothing outside JAX) into plain lists,
 clipped to the window, in seconds from its start:
 
-``ops``      ``(op, start, dur)`` of every operation on the first chip's
-             ``XLA Ops`` line; ``op`` is the HLO instruction's name without
-             its number (``paged_attention`` for the Pallas kernel,
-             ``fusion``, ``while`` for a loop that holds other operations);
-``modules``  ``(program, start, dur, share)`` of every program run on the
-             first chip's ``XLA Modules`` line, ``program`` as jit names it
+``ops``      ``(op, start, dur)`` of every operation on the first of the
+             cell's chips' ``XLA Ops`` line; ``op`` is the HLO
+             instruction's name without its number (``paged_attention``
+             for the Pallas kernel, ``fusion``, ``all-gather``, ``while``
+             for a loop that holds other operations);
+``modules``  ``(program, start, dur, share)`` of every program run on that
+             chip's ``XLA Modules`` line, ``program`` as jit names it
              (``jit__decode_paged_impl``), ``share`` the part of the run
              that lies inside the window;
 ``host``     ``(thread, name, start, dur)`` of host events of 50 us or
-             more (the profiler's Python frames, ``$file:line function``,
-             among them); ``thread`` is the line's name and index;
-``busy_s``   union of the operations' intervals, averaged over the chips;
-``trace_window_s`` the window's length on the trace clock.
+             more: the program's spans (``serve.*``, ``pipe.*``) and the
+             runtime's own events; ``thread`` is the line's name and
+             index. The Python tracer is off, so no frame is recorded
+             (the committed records under ``testdata/`` predate that and
+             hold frames, ``$file:line function``);
+``busy_s``   union of the operations' intervals, averaged over the cell's
+             chips;
+``trace_window_s`` the window's length on the trace clock;
+``chips``    the cell's chips: the device planes of the lowest indices
+             (``jax.devices()[:chips]``); a host's other chips, idle in a
+             smaller cell, are left out. Readers take a record without
+             it, such as those under ``testdata/``, as one chip.
 
 ``breakdown`` turns records into the result's ``breakdown``.
 """
@@ -33,16 +42,17 @@ import shutil
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["WINDOW", "Profile", "records_from_file", "union_s", "breakdown",
-           "op_name", "save_records", "load_records"]
+__all__ = ["WINDOW", "Profile", "records_from_file", "reduce_window",
+           "union_s", "breakdown", "op_name", "save_records", "load_records"]
 
 WINDOW = "benchmark_window"
 HOST_MIN_NS = 50_000
 #: operations that only hold others (their time is their body's)
 CONTAINERS = ("while", "conditional", "call")
 #: the engine's decode stage: the thread that runs it is the one whose
-#: frames say what kept the device waiting
-ENGINE_FRAME = "_st_decode"
+#: events say what kept the device waiting. It is found by its span, or
+#: in traces recorded with the Python tracer, by its frame
+ENGINE_MARKS = ("pipe.decode", "_st_decode")
 
 
 class Profile:
@@ -57,7 +67,11 @@ class Profile:
         import jax
         shutil.rmtree(self.dir, ignore_errors=True)
         self.dir.mkdir(parents=True)
-        jax.profiler.start_trace(str(self.dir))
+        # no Python tracer: a frame per call slows the host it measures and
+        # swells the trace; the program's spans name what the host does
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(self.dir), profiler_options=opts)
 
     def open_window(self) -> None:
         import jax
@@ -111,7 +125,7 @@ def union_s(intervals: List[Tuple[float, float]]) -> float:
     return total
 
 
-def records_from_file(path: str) -> Dict:
+def records_from_file(path: str, chips: int) -> Dict:
     import jax
     pd = jax.profiler.ProfileData.from_file(path)
     w0 = w1 = None
@@ -139,7 +153,14 @@ def records_from_file(path: str) -> Dict:
                                       e.duration_ns) for e in line.events]
     if w0 is None:
         raise ValueError(f"{path}: no {WINDOW!r} host event")
+    return reduce_window(w0, w1, host_raw, devices, chips)
 
+
+def reduce_window(w0: int, w1: int, host_raw: List, devices: Dict,
+                  chips: int) -> Dict:
+    """The records of the window ``[w0, w1)`` (trace nanoseconds) from the
+    host events and each device plane's ``ops`` and ``modules``, keyed by
+    device index, of which the ``chips`` lowest belong to the cell."""
     def clip(evs, share=False):
         out = []
         for ev in evs:
@@ -150,14 +171,16 @@ def records_from_file(path: str) -> Dict:
                 out.append(row + ((b - a) / dur,) if share else row)
         return out
 
+    cell = [devices[i] for i in sorted(devices)[:chips]]
     busy = [union_s([(s, s + d) for _, s, d in clip(dv["ops"])])
-            for dv in devices.values()]
-    first = devices[min(devices)] if devices else {"ops": [], "modules": []}
+            for dv in cell]
+    first = cell[0] if cell else {"ops": [], "modules": []}
     return {"ops": clip(first["ops"]),
             "modules": clip(first["modules"], share=True),
             "host": clip(host_raw),
             "busy_s": sum(busy) / len(busy) if busy else 0.0,
-            "trace_window_s": (w1 - w0) * 1e-9, "n_devices": len(devices)}
+            "trace_window_s": (w1 - w0) * 1e-9, "n_devices": len(cell),
+            "chips": chips}
 
 
 def _program_at(modules, starts, t: float) -> str:
@@ -190,7 +213,8 @@ def breakdown(rec: Dict, top: int = 10) -> Dict[str, list]:
     if rec["trace_window_s"] > t:
         gaps.append((t, rec["trace_window_s"]))
     gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
-    engine = {h[0] for h in rec["host"] if ENGINE_FRAME in h[1]}
+    engine = {h[0] for h in rec["host"]
+              if any(m in h[1] for m in ENGINE_MARKS)}
     host = [h for h in rec["host"] if h[0] in engine] if engine \
         else rec["host"]
     named = []
