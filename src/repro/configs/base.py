@@ -32,9 +32,9 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
-    pos_emb: str = "rope"          # rope | sinusoidal
+    pos_emb: str = "rope"          # rope | sinusoidal | none
     # mlp
-    mlp_gated: bool = True         # SwiGLU if True, GELU otherwise
+    mlp_act: str = "swiglu"        # swiglu (gated) | gelu | relu2
     tie_embeddings: bool = False
     rms_eps: float = 1e-5
     # MoE
@@ -48,13 +48,21 @@ class ModelConfig:
     router_aux_weight: float = 1e-3
     norm_topk_prob: bool = True
     # SSM (mamba)
-    ssm: bool = False
+    ssm: bool = False              # a homogeneous Mamba stack (falcon-mamba,
+    #                                zamba2's backbone); a layer_pattern
+    #                                places its Mamba layers itself
     ssm_version: int = 1           # 1 = Mamba, 2 = Mamba2 (SSD)
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_head_dim: int = 64         # mamba2
     dt_rank: int = 0               # 0 => d_model // 16  (mamba1)
+    ssm_groups: int = 1            # mamba2 B/C groups, each shared by
+    #                                ssm_heads // ssm_groups heads
+    # per-layer pattern (nemotron-h): one character a layer, "M" Mamba-2,
+    # "-" MLP, "*" attention, each ``x += mixer(RMSNorm(x))``; empty = the
+    # family's homogeneous stack
+    layer_pattern: str = ""
     # hybrid (zamba2): shared transformer block applied every k SSM layers
     hybrid_attn_every: int = 0
     # modality frontend (STUB per assignment: precomputed embeddings)
@@ -108,6 +116,29 @@ class ModelConfig:
         return self.num_heads == 0
 
     @property
+    def mlp_gated(self) -> bool:
+        """SwiGLU: the MLP has a gate projection."""
+        return self.mlp_act == "swiglu"
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep attention KV (the paged pool's layer axis)."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("*")
+        return self.num_layers
+
+    @property
+    def mamba_layers(self) -> int:
+        """Mamba layers of a layer pattern (their per-row recurrent state
+        lives beside the paged pool)."""
+        return self.layer_pattern.count("M")
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Mamba-2 conv channels: x, then one B and one C per group."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def subquadratic(self) -> bool:
         """True if the arch can serve 500k-token contexts (SSM/hybrid)."""
         return self.family in ("ssm", "hybrid")
@@ -118,6 +149,14 @@ class ModelConfig:
         n = V * D  # embedding
         if not self.tie_embeddings:
             n += D * V
+        if self.layer_pattern:
+            H, KV, hd = self.num_heads, self.num_kv_heads, self.hd
+            dI, nh, C = self.d_inner, self.ssm_heads, self.ssm_conv_dim
+            per = {"M": D * (dI + C + nh) + C * (self.ssm_conv + 1)
+                   + 3 * nh + dI + dI * D,
+                   "-": (3 if self.mlp_gated else 2) * D * F,
+                   "*": D * H * hd + 2 * D * KV * hd + H * hd * D}
+            return n + D + sum(per[k] + D for k in self.layer_pattern)
         per_layer = 0
         if not self.attention_free and self.hybrid_attn_every == 0:
             H, KV, hd = self.num_heads, self.num_kv_heads, self.hd
@@ -163,11 +202,17 @@ class ModelConfig:
         return self.param_count() - dead
 
     def smoke(self) -> "ModelConfig":
-        """Reduced same-family config for CPU smoke tests."""
+        """Reduced same-family config for CPU smoke tests (a layer pattern
+        keeps its kinds: ``M-*M-``, two B/C groups)."""
+        mamba = self.ssm or "M" in self.layer_pattern
+        pattern = "M-*M-" if self.layer_pattern else ""
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            num_layers=2 if self.hybrid_attn_every == 0 else 3,
+            num_layers=len(pattern) or (
+                2 if self.hybrid_attn_every == 0 else 3),
+            layer_pattern=pattern,
+            ssm_groups=2 if self.layer_pattern else self.ssm_groups,
             d_model=64,
             num_heads=0 if self.attention_free else 4,
             num_kv_heads=0 if self.attention_free else max(
@@ -179,8 +224,8 @@ class ModelConfig:
             num_experts_per_tok=min(self.num_experts_per_tok, 2),
             moe_d_ff=32 if self.moe else 0,
             shared_expert_d_ff=48 if self.shared_expert_d_ff else 0,
-            ssm_state=16 if self.ssm else 0,
-            ssm_head_dim=16 if self.ssm else 64,
+            ssm_state=16 if mamba else 0,
+            ssm_head_dim=16 if mamba else 64,
             dt_rank=8 if self.ssm and self.ssm_version == 1 else 0,
             hybrid_attn_every=2 if self.hybrid_attn_every else 0,
             frontend_tokens=8 if self.frontend != "none" else 0,

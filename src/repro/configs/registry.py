@@ -14,10 +14,11 @@ from .musicgen_large import CONFIG as _musicgen
 from .falcon_mamba_7b import CONFIG as _falcon_mamba
 from .zamba2_1_2b import CONFIG as _zamba2
 from .internvl2_1b import CONFIG as _internvl2
+from .nemotron_h_47b import CONFIG as _nemotron_h
 
 ARCHS: Dict[str, ModelConfig] = {c.name: c for c in (
     _qwen25_32b, _stablelm, _qwen3, _nemo, _qwen2moe,
-    _arctic, _musicgen, _falcon_mamba, _zamba2, _internvl2,
+    _arctic, _musicgen, _falcon_mamba, _zamba2, _internvl2, _nemotron_h,
 )}
 
 

@@ -30,6 +30,9 @@ __all__ = ["init_attention", "attention", "decode_attention",
            "paged_prefill_window_attention", "NEG_INF"]
 
 NEG_INF = -2.0 ** 30  # large-but-finite: keeps bf16 softmax NaN-free
+#: a prefill window whose f32 scores (rows x heads x window x cached keys)
+#: pass this many bytes attends row by row
+WINDOW_SCORES_BYTES = 1 << 30
 
 
 def init_attention(key, cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
@@ -73,8 +76,9 @@ def _project_qkv(p, x, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.pos_emb != "none":
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -270,18 +274,31 @@ def paged_prefill_window_attention(p, x, cfg: ModelConfig, pool_kv, layer,
     q, k, v = _project_qkv(p, x, cfg, positions)
     pool_kv = scatter_token_window(pool_kv, layer, k, v, tables,
                                    positions[:, 0], valid)
-    ks, vs = gather_pages(pool_kv, layer, tables, hd)  # (B, KV, T, hd)
-    T = ks.shape[2]
     qg = q.reshape(B, C, KV, G, hd)
-    s = jnp.einsum("bckgh,bksh->bkgcs", qg, ks,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    kpos = jnp.arange(T, dtype=jnp.int32)
-    mask = kpos[None, None, None, None, :] <= positions[:, None, None, :, None]
-    s = jnp.where(mask, s, NEG_INF)
-    pmax = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - pmax)
-    probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(vs.dtype)
-    out = jnp.einsum("bkgcs,bksh->bckgh", probs, vs)
+
+    def attend(qg, tables, positions):
+        ks, vs = gather_pages(pool_kv, layer, tables, hd)  # (B, KV, T, hd)
+        T = ks.shape[2]
+        s = jnp.einsum("bckgh,bksh->bkgcs", qg, ks,
+                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+        kpos = jnp.arange(T, dtype=jnp.int32)
+        mask = kpos[None, None, None, None, :] \
+            <= positions[:, None, None, :, None]
+        s = jnp.where(mask, s, NEG_INF)
+        pmax = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - pmax)
+        probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(vs.dtype)
+        return jnp.einsum("bkgcs,bksh->bckgh", probs, vs)
+
+    T = tables.shape[1] * pool_kv.shape[4]
+    if B * H * C * T * 4 > WINDOW_SCORES_BYTES:
+        # the window's f32 scores would not fit beside a deployment's
+        # weights and pool: attend one row at a time
+        out = jax.lax.map(
+            lambda a: attend(a[0][None], a[1][None], a[2][None])[0],
+            (qg, tables, positions))
+    else:
+        out = attend(qg, tables, positions)
     out2 = out.reshape(B, C, H * hd).astype(cdt)
     if out2.shape[-1] != p["wo"].shape[0]:   # serve TP: concat local heads
         out2 = gather_tp(out2, -1)

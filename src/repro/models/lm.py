@@ -9,6 +9,12 @@ Families:
                         group followed by ONE SHARED transformer block whose
                         weights are reused across groups, fed with
                         concat([hidden, embeddings]) @ fused_proj
+  layer pattern       : (nemotron-h) one mixer a layer as `layer_pattern`
+                        says — Mamba-2 "M", MLP "-", attention "*" — each
+                        `x += mixer(RMSNorm(x))`, unrolled over a list of
+                        per-layer params; serving pages the attention
+                        layers' KV and carries the Mamba layers' (conv, h)
+                        per row beside the pool
 
 Layer stacks are scan-over-layers (stacked params, `jax.lax.scan`) with
 optional remat — this keeps HLO size O(1) in depth, which is what makes the
@@ -39,7 +45,8 @@ from ..distributed.sharding import (constrain, manual_serve_map,
 from .attention import (attention, decode_attention, decode_attention_rows,
                         init_attention)
 from .layers import dtype_of, normal_init, rms_norm, sinusoidal_positions
-from .mamba import init_mamba, init_mamba_state, mamba_forward, mamba_step
+from .mamba import (init_mamba, init_mamba_state, mamba_forward,
+                    mamba_step, mamba_window)
 from .mlp import init_mlp, mlp
 from .moe import init_moe, moe_layer
 
@@ -79,6 +86,31 @@ def _init_shared_block(key, cfg: ModelConfig) -> Dict[str, Any]:
     return p
 
 
+def _init_pattern_layer(key, kind: str, cfg: ModelConfig) -> Dict[str, Any]:
+    """One layer of a layer pattern: its norm and its mixer's params."""
+    p: Dict[str, Any] = {"ln1": jnp.ones((cfg.d_model,),
+                                         dtype_of(cfg.param_dtype))}
+    init = {"M": init_mamba, "-": init_mlp, "*": init_attention}.get(kind)
+    if init is None:
+        raise ValueError(f"{cfg.name}: unknown layer kind {kind!r} in "
+                         f"layer_pattern (M, - or *)")
+    p.update(init(key, cfg))
+    return p
+
+
+def _pattern_stack(cfg: ModelConfig, params, x, mixers):
+    """``x += mixer(RMSNorm(x))`` over the layer pattern, unrolled:
+    ``mixers[kind](p, h, j)`` gives a layer's output, ``j`` its index among
+    the pattern's layers of that kind (the paged pool's layer index for
+    attention, the recurrent state's for Mamba)."""
+    seen = {"M": 0, "-": 0, "*": 0}
+    for kind, p in zip(cfg.layer_pattern, params["layers"]):
+        j = seen[kind]
+        seen[kind] += 1
+        x = x + mixers[kind](p, rms_norm(x, p["ln1"], cfg.rms_eps), j)
+    return x
+
+
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     pdt = dtype_of(cfg.param_dtype)
     k_embed, k_blocks, k_head, k_shared = jax.random.split(key, 4)
@@ -88,7 +120,15 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         "final_norm": jnp.ones((cfg.d_model,), pdt),
     }
     L = cfg.num_layers
-    if cfg.hybrid_attn_every:
+    if cfg.layer_pattern:
+        if len(cfg.layer_pattern) != L:
+            raise ValueError(f"{cfg.name}: layer_pattern has "
+                             f"{len(cfg.layer_pattern)} layers, num_layers "
+                             f"{L}")
+        kl = jax.random.split(k_blocks, L)
+        params["layers"] = [_init_pattern_layer(kl[i], kind, cfg)
+                            for i, kind in enumerate(cfg.layer_pattern)]
+    elif cfg.hybrid_attn_every:
         every = cfg.hybrid_attn_every
         G, tail = L // every, L % every
         kg = jax.random.split(k_blocks, G * every).reshape(G, every, 2)
@@ -151,6 +191,16 @@ def _stack_scan(stacked, x, cfg, positions, remat: bool):
     return x, aux
 
 
+def _head_logits(cfg: ModelConfig, params, x):
+    """Final RMSNorm and the head over last-position hidden states (B, D):
+    f32 logits (B, padded_vocab)."""
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.einsum("bd,dv->bv", x,
+                      head.astype(dtype_of(cfg.compute_dtype)),
+                      preferred_element_type=jnp.float32)
+
+
 # ------------------------------------------------------------------ forward
 def _embed(cfg: ModelConfig, params, tokens, frontend_embeds):
     cdt = dtype_of(cfg.compute_dtype)
@@ -174,7 +224,12 @@ def forward(cfg: ModelConfig, params, tokens,
     x, positions = _embed(cfg, params, tokens, frontend_embeds)
     x = constrain(x, "dp", None, None)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.hybrid_attn_every:
+    if cfg.layer_pattern:
+        x = _pattern_stack(cfg, params, x, {
+            "M": lambda p, h, j: mamba_forward(p, h, cfg),
+            "-": lambda p, h, j: mlp(p, h, cfg),
+            "*": lambda p, h, j: attention(p, h, cfg, positions)})
+    elif cfg.hybrid_attn_every:
         x0 = x
         sb = params["shared_block"]
 
@@ -243,7 +298,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     cdt = dtype_of(cfg.compute_dtype)
     L = cfg.num_layers
     cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
-    if cfg.hybrid_attn_every:
+    if cfg.layer_pattern:
+        La, Lm = cfg.attn_layers, cfg.mamba_layers
+        conv, h = init_mamba_state(cfg, batch, cdt)
+        cache["ssm"] = (jnp.tile(conv[None], (Lm,) + (1,) * conv.ndim),
+                        jnp.tile(h[None], (Lm,) + (1,) * h.ndim))
+        KV, hd = cfg.num_kv_heads, cfg.hd
+        cache["k"] = jnp.zeros((La, batch, KV, max_len, hd), cdt)
+        cache["v"] = jnp.zeros((La, batch, KV, max_len, hd), cdt)
+    elif cfg.hybrid_attn_every:
         every = cfg.hybrid_attn_every
         G, tail = L // every, L % every
         conv, h = init_mamba_state(cfg, batch, cdt)
@@ -308,6 +371,22 @@ def _shared_block_decode(p, x1, x0, cfg, ck, cv, pos):
     return x1 + h, ck, cv
 
 
+def _mamba_decode(cfg: ModelConfig, st, active, p, h, j):
+    """One token through Mamba layer ``j`` of a layer pattern, its (conv,
+    h) read from and written back to ``st["ssm"]`` (stacked over the
+    pattern's Mamba layers). With ``active`` ((B,) bool), inactive rows
+    keep their state: a row mid-prefill or parked between windows must not
+    step."""
+    conv, hs = st["ssm"]
+    y, (c2, h2) = mamba_step(p, h, cfg, (conv[j], hs[j]))
+    c2 = c2.astype(conv.dtype)
+    if active is not None:
+        c2 = jnp.where(active[:, None, None], c2, conv[j])
+        h2 = jnp.where(active[:, None, None, None], h2, hs[j])
+    st["ssm"] = (conv.at[j].set(c2), hs.at[j].set(h2))
+    return y
+
+
 def decode_step(cfg: ModelConfig, params, cache, token
                 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """One decode step. token: (B,) int32 current input token.
@@ -319,7 +398,22 @@ def decode_step(cfg: ModelConfig, params, cache, token
         x1 = x1 + sinusoidal_positions(pos[None], cfg.d_model
                                        ).astype(cdt)[0]
     new_cache = dict(cache)
-    if cfg.hybrid_attn_every:
+    if cfg.layer_pattern:
+        st = {"k": cache["k"], "v": cache["v"], "ssm": cache["ssm"]}
+
+        def attn(p, h, j):
+            y, ck, cv = decode_attention(p, h[:, None, :], cfg, st["k"][j],
+                                         st["v"][j], pos)
+            st["k"] = st["k"].at[j].set(ck)
+            st["v"] = st["v"].at[j].set(cv)
+            return y[:, 0]
+
+        x1 = _pattern_stack(cfg, params, x1, {
+            "M": functools.partial(_mamba_decode, cfg, st, None),
+            "-": lambda p, h, j: mlp(p, h[:, None, :], cfg)[:, 0],
+            "*": attn})
+        new_cache.update(st)
+    elif cfg.hybrid_attn_every:
         x0 = x1
         sb = params["shared_block"]
 
@@ -366,12 +460,8 @@ def decode_step(cfg: ModelConfig, params, cache, token
         x1, (k, v) = jax.lax.scan(layer, x1,
                                   (params["blocks"], cache["k"], cache["v"]))
         new_cache["k"], new_cache["v"] = k, v
-    x1 = rms_norm(x1, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x1, head.astype(cdt),
-                        preferred_element_type=jnp.float32)
     new_cache["pos"] = pos + 1
-    return logits, new_cache
+    return _head_logits(cfg, params, x1), new_cache
 
 
 def _shared_block_decode_rows(p, x1, x0, cfg, ck, cv, pos):
@@ -460,11 +550,7 @@ def decode_step_slots(cfg: ModelConfig, params, state, token, pos
 
         x1, ssm = jax.lax.scan(layer, x1, (params["blocks"], state["ssm"]))
         new_state["ssm"] = ssm
-    x1 = rms_norm(x1, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x1, head.astype(cdt),
-                        preferred_element_type=jnp.float32)
-    return logits, new_state
+    return _head_logits(cfg, params, x1), new_state
 
 
 def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
@@ -487,12 +573,15 @@ def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
     the gather-free kernel/page-loop or the materializing ``"gather"``
     oracle; see :func:`repro.models.attention.paged_decode_attention`.
     Returns (logits (B, padded_vocab) f32, pool_kv).
-    Attention architectures only — SSM/hybrid states are O(1) per sequence
-    and take the contiguous path.
+    Attention architectures, and layer-pattern models, whose ``pool_kv`` is
+    the pair ``(pool, (conv, h))``: the pool holds the attention layers
+    (``L`` = their count) and ``(conv, h)`` the Mamba layers' per-row state
+    (:func:`init_cache`'s ``"ssm"`` layout), stepped for active rows only.
+    zamba2/falcon-mamba take the slot pool (:func:`decode_step_slots`).
     """
     if cfg.ssm or cfg.hybrid_attn_every:
         raise ValueError(f"{cfg.name}: paged decode requires a pure "
-                         "attention architecture")
+                         "attention architecture or a layer pattern")
     from .attention import paged_decode_attention
 
     cdt = dtype_of(cfg.compute_dtype)
@@ -500,6 +589,21 @@ def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
     x1 = jnp.take(params["embed"], token, axis=0).astype(cdt)
     if cfg.pos_emb == "sinusoidal":
         x1 = x1 + sinusoidal_positions(pos, cfg.d_model).astype(cdt)
+    if cfg.layer_pattern:
+        kv, ssm = pool_kv
+        st = {"kv": kv, "ssm": ssm}
+
+        def attn(p, h, j):
+            y, st["kv"] = paged_decode_attention(
+                p, h[:, None, :], cfg, st["kv"], jnp.int32(j), tables, pos,
+                active, impl=impl)
+            return y[:, 0]
+
+        x1 = _pattern_stack(cfg, params, x1, {
+            "M": functools.partial(_mamba_decode, cfg, st, active),
+            "-": lambda p, h, j: mlp(p, h[:, None, :], cfg)[:, 0],
+            "*": attn})
+        return _head_logits(cfg, params, x1), (st["kv"], st["ssm"])
 
     def paged_attn(lp, h1, layer_cache):
         pkv, li = layer_cache
@@ -520,11 +624,7 @@ def decode_step_paged(cfg: ModelConfig, params, pool_kv, tables,
     (x1, pool_kv), _ = jax.lax.scan(
         layer, (x1, pool_kv),
         (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
-    x1 = rms_norm(x1, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x1, head.astype(cdt),
-                        preferred_element_type=jnp.float32)
-    return logits, pool_kv
+    return _head_logits(cfg, params, x1), pool_kv
 
 
 def _decode_chunk_scan(step, state, carry, n: int):
@@ -668,11 +768,16 @@ def prefill_window_paged(cfg: ModelConfig, params, pool_kv, tables, tokens,
     tails); last_idx: (B,) int32 window column of each row's FINAL prompt
     token, clipped into range — its logits seed the row's first generated
     token, consumed only for rows whose prompt ends in this window.
-    Returns (first_tokens (B,) int32 greedy, pool_kv). Attention archs only.
+    Returns (first_tokens (B,) int32 greedy, pool_kv). Attention archs, and
+    layer-pattern models, whose ``pool_kv`` is ``(pool, (conv, h))`` as in
+    :func:`decode_step_paged`: their Mamba layers run the window from each
+    row's carried state (:func:`repro.models.mamba.mamba_window`) and hand
+    it on, so every window of a prompt, window 0 included, comes through
+    here. A row with no valid entry keeps its state.
     """
     if cfg.ssm or cfg.hybrid_attn_every:
         raise ValueError(f"{cfg.name}: paged chunked prefill requires a "
-                         "pure attention architecture")
+                         "pure attention architecture or a layer pattern")
     if _serve_tp_active(cfg, ctx):
         pspec = serve_param_specs(cfg, params, ctx)
         pool = serve_pool_spec(cfg, ctx)
@@ -693,6 +798,27 @@ def prefill_window_paged(cfg: ModelConfig, params, pool_kv, tables, tokens,
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
     if cfg.pos_emb == "sinusoidal":
         x = x + sinusoidal_positions(positions, cfg.d_model).astype(cdt)
+
+    if cfg.layer_pattern:
+        kv, ssm = pool_kv
+        st = {"kv": kv, "ssm": ssm}
+
+        def attn(p, h, j):
+            y, st["kv"] = paged_prefill_window_attention(
+                p, h, cfg, st["kv"], jnp.int32(j), tables, positions, valid)
+            return y
+
+        def mamba(p, h, j):
+            conv, hs = st["ssm"]
+            y, (c2, h2) = mamba_window(p, h, cfg, (conv[j], hs[j]), valid)
+            st["ssm"] = (conv.at[j].set(c2), hs.at[j].set(h2))
+            return y
+
+        x = _pattern_stack(cfg, params, x, {
+            "M": mamba, "-": lambda p, h, j: mlp(p, h, cfg), "*": attn})
+        logits = _head_logits(cfg, params, x[jnp.arange(B), last_idx])
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                (st["kv"], st["ssm"]))
 
     def win_attn(lp, h, layer_cache):
         pkv, li = layer_cache
@@ -766,7 +892,30 @@ def prefill(cfg: ModelConfig, params, tokens, max_len: int = 0,
     cache = init_cache(cfg, B, max_len)
     cdt = dtype_of(cfg.compute_dtype)
 
-    if cfg.hybrid_attn_every:
+    if cfg.layer_pattern:
+        ks, vs, convs, hs = [], [], [], []
+        pad = max_len - total
+
+        def attn(p, h, j):
+            y, (k, v) = attention(p, h, cfg, positions, return_kv=True)
+            k, v = (jnp.pad(t.transpose(0, 2, 1, 3),
+                            ((0, 0), (0, 0), (0, pad), (0, 0))).astype(cdt)
+                    for t in (k, v))
+            ks.append(k)
+            vs.append(v)
+            return y
+
+        def mamba(p, h, j):
+            y, (conv, hh) = mamba_forward(p, h, cfg, return_state=True)
+            convs.append(conv.astype(cdt))
+            hs.append(hh)
+            return y
+
+        x = _pattern_stack(cfg, params, x, {
+            "M": mamba, "-": lambda p, h, j: mlp(p, h, cfg), "*": attn})
+        cache["k"], cache["v"] = jnp.stack(ks), jnp.stack(vs)
+        cache["ssm"] = (jnp.stack(convs), jnp.stack(hs))
+    elif cfg.hybrid_attn_every:
         x0 = x
         sb = params["shared_block"]
         g_conv, g_h = [], []
@@ -861,9 +1010,5 @@ def prefill(cfg: ModelConfig, params, tokens, max_len: int = 0,
         cache["k"], cache["v"] = k, v
     x_last = x[:, -1] if last_positions is None \
         else x[jnp.arange(x.shape[0]), last_positions]
-    x_last = rms_norm(x_last, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x_last, head.astype(cdt),
-                        preferred_element_type=jnp.float32)
     cache["pos"] = jnp.asarray(total, jnp.int32)
-    return logits, cache
+    return _head_logits(cfg, params, x_last), cache

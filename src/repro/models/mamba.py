@@ -5,14 +5,17 @@ recurrence h_t = a_t*h_{t-1} + b_t runs as a log-depth associative scan;
 chunks are linked by a lax.scan carry. Activation memory is O(S_chunk * dI * N)
 instead of O(S * dI * N).
 
-Mamba2 (zamba2): the **SSD dual form** — scalar-per-head decay turns the
-recurrence into (i) a causal matmul within each chunk (MXU-friendly) and
-(ii) a tiny cross-chunk state recurrence. This is the TPU-native adaptation:
-the GPU implementation's fused scan kernel becomes matmuls + one short scan.
+Mamba2 (zamba2; nemotron-h, with 8 B/C groups): the **SSD dual form** —
+scalar-per-head decay turns the recurrence into (i) a causal matmul within
+each chunk (MXU-friendly) and (ii) a tiny cross-chunk state recurrence. This
+is the TPU-native adaptation: the GPU implementation's fused scan kernel
+becomes matmuls + one short scan.
 
 Both blocks expose ``*_step`` single-token decode paths carrying
 (conv_buffer, ssm_state) — this is what makes 500k-token decoding O(1) per
 step (no KV cache), the reason long_500k is assigned to these archs.
+:func:`mamba_window` runs Mamba-2 over one chunked-prefill window from a
+carried (conv window, h), so a prompt prefills across windows.
 """
 from __future__ import annotations
 
@@ -25,14 +28,21 @@ from ..configs.base import ModelConfig
 from ..distributed.sharding import constrain
 from .layers import dense_init, dtype_of, normal_init, rms_norm
 
-__all__ = ["init_mamba", "mamba_forward", "mamba_step", "init_mamba_state"]
+__all__ = ["init_mamba", "mamba_forward", "mamba_window", "mamba_step",
+           "init_mamba_state"]
 
 
-def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal conv. x: (B,S,C); w: (C,K); b: (C,)."""
+def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
+                 prefix=None) -> jnp.ndarray:
+    """Depthwise causal conv. x: (B,S,C); w: (C,K); b: (C,). ``prefix``
+    (B,K-1,C): the K-1 inputs before x (a carried conv window); zeros
+    when None."""
     B, S, C = x.shape
     K = w.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    if prefix is None:
+        xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    else:
+        xp = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
     y = sum(xp[:, k:k + S, :] * w[:, k] for k in range(K))
     return y + b
 
@@ -172,13 +182,13 @@ def _m1_step(p, x1, cfg: ModelConfig, state):
 
 # ===================================================================== Mamba2
 def _init_m2(key, cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
-    D, dI, N, K = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_conv
+    D, dI, K = cfg.d_model, cfg.d_inner, cfg.ssm_conv
     nh = cfg.ssm_heads
     pdt = dtype_of(cfg.param_dtype)
     ks = jax.random.split(key, 4)
-    conv_dim = dI + 2 * N
+    conv_dim = cfg.ssm_conv_dim
     return {
-        "in_proj": dense_init(ks[0], (D, 2 * dI + 2 * N + nh), pdt),
+        "in_proj": dense_init(ks[0], (D, dI + conv_dim + nh), pdt),
         "conv_w": normal_init(ks[1], (conv_dim, K), 0.2, pdt),
         "conv_b": jnp.zeros((conv_dim,), pdt),
         "A_log": jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(jnp.float32),
@@ -192,10 +202,11 @@ def _init_m2(key, cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
 
 
 def _ssd_scan(xh, dt, A, Bc, Cc, h0, chunk: int):
-    """SSD dual form. xh: (B,S,nh,hp); dt: (B,S,nh) f32; A: (nh,) f32;
-    Bc,Cc: (B,S,N); h0: (B,nh,hp,N) f32. Returns y (B,S,nh,hp), final h."""
-    B_, S, nh, hp = xh.shape
-    N = Bc.shape[-1]
+    """SSD dual form over B/C groups. xh: (B,S,G,Hg,hp); dt: (B,S,G,Hg)
+    f32; A: (G,Hg) f32; Bc,Cc: (B,S,G,N); h0: (B,G,Hg,hp,N) f32 — head
+    ``g * Hg + j`` reads group g's B and C. Returns y (B,S,G,Hg,hp) and the
+    final h."""
+    B_, S = xh.shape[:2]
     chunk = min(chunk, S)
     if S % chunk:
         chunk = S
@@ -204,24 +215,25 @@ def _ssd_scan(xh, dt, A, Bc, Cc, h0, chunk: int):
     def to_chunks(t):
         return t.reshape(B_, nch, chunk, *t.shape[2:]).swapaxes(0, 1)
 
-    loga = dt * A                                              # (B,S,nh) <= 0
+    loga = dt * A                                              # <= 0
 
     def chunk_step(h, inp):
-        x_c, dt_c, la_c, b_c, c_c = inp        # (B,c,nh,hp) (B,c,nh) (B,c,N)
-        L = jnp.cumsum(la_c, axis=1)                           # (B,c,nh)
+        # (B,c,G,Hg,hp) (B,c,G,Hg) (B,c,G,Hg) (B,c,G,N) (B,c,G,N)
+        x_c, dt_c, la_c, b_c, c_c = inp
+        L = jnp.cumsum(la_c, axis=1)                           # (B,c,G,Hg)
         # intra-chunk: causal "attention" with decay
-        seg = L[:, :, None, :] - L[:, None, :, :]              # (B,c,c,nh)
+        seg = L[:, :, None] - L[:, None]                       # (B,t,s,G,Hg)
         tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-        decay = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)
-        cb = jnp.einsum("btn,bsn->bts", c_c, b_c)              # (B,c,c)
-        w = cb[..., None] * decay * dt_c[:, None, :, :]        # (B,t,s,nh)
-        y = jnp.einsum("btsh,bshp->bthp", w, x_c)
+        decay = jnp.where(tri[None, :, :, None, None], jnp.exp(seg), 0.0)
+        cb = jnp.einsum("btgn,bsgn->btsg", c_c, b_c)           # (B,t,s,G)
+        w = cb[..., None] * decay * dt_c[:, None]              # (B,t,s,G,Hg)
+        y = jnp.einsum("btsgh,bsghp->btghp", w, x_c)
         # inter-chunk: contribution of carried state
-        y = y + jnp.einsum("btn,bhpn->bthp", c_c, h) \
+        y = y + jnp.einsum("btgn,bghpn->btghp", c_c, h) \
             * jnp.exp(L)[..., None]
         # chunk state: sum_s exp(L_last - L_s) dt_s x_s B_s^T
-        rdecay = jnp.exp(L[:, -1:, :] - L)                     # (B,c,nh)
-        hc = jnp.einsum("bshp,bsn->bhpn",
+        rdecay = jnp.exp(L[:, -1:] - L)                        # (B,c,G,Hg)
+        hc = jnp.einsum("bsghp,bsgn->bghpn",
                         x_c * (dt_c * rdecay)[..., None], b_c)
         h = h * jnp.exp(L[:, -1])[..., None, None] + hc
         return h, y
@@ -231,66 +243,125 @@ def _ssd_scan(xh, dt, A, Bc, Cc, h0, chunk: int):
         (to_chunks(xh.astype(jnp.float32)), to_chunks(dt),
          to_chunks(loga), to_chunks(Bc.astype(jnp.float32)),
          to_chunks(Cc.astype(jnp.float32))))
-    y = ys.swapaxes(0, 1).reshape(B_, S, nh, hp)
+    y = ys.swapaxes(0, 1).reshape(xh.shape)
     return y, hT
 
 
-def _m2_forward(p, x, cfg: ModelConfig, h0=None, return_state=False):
-    B, S, D = x.shape
-    dI, N, nh, hp = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+def _m2_split(p, x, cfg: ModelConfig):
+    """In-projection of Mamba-2: (z, xBC before the conv, dt before its
+    softplus)."""
+    dI, C = cfg.d_inner, cfg.ssm_conv_dim
     cdt = dtype_of(cfg.compute_dtype)
     zxbcdt = x @ p["in_proj"].astype(cdt)
-    z, xh, Bc, Cc, dt = jnp.split(
-        zxbcdt, [dI, 2 * dI, 2 * dI + N, 2 * dI + 2 * N], axis=-1)
-    xbc = jnp.concatenate([xh, Bc, Cc], axis=-1)
-    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"].astype(cdt),
-                                   p["conv_b"].astype(cdt)))
-    xh, Bc, Cc = jnp.split(xbc, [dI, dI + N], axis=-1)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,nh)
+    return jnp.split(zxbcdt, [dI, dI + C], axis=-1)
+
+
+def _gated_norm(p, y, z, cfg: ModelConfig):
+    """RMSNorm of ``y * silu(z)`` over each B/C group's d_inner / groups
+    channels (all of d_inner with one group)."""
+    g = y * jax.nn.silu(z)
+    G = cfg.ssm_groups
+    if G == 1:
+        return rms_norm(g, p["ssm_norm"], cfg.rms_eps)
+    shp = g.shape
+    g = g.reshape(shp[:-1] + (G, shp[-1] // G))
+    return rms_norm(g, p["ssm_norm"].reshape(G, -1), cfg.rms_eps) \
+        .reshape(shp)
+
+
+def _m2_mix(p, z, xbc, dt, cfg: ModelConfig, h0):
+    """Mamba-2 after the conv: SSD scan from ``h0``, the D skip, the gated
+    norm and the out-projection. xbc: (B,S,conv_dim) post-conv; dt:
+    (B,S,nh) f32 post-softplus. Returns (out, hT)."""
+    B, S = xbc.shape[:2]
+    dI, N, nh, hp = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_groups
+    Hg = nh // G
+    cdt = dtype_of(cfg.compute_dtype)
+    xh, Bc, Cc = jnp.split(xbc, [dI, dI + G * N], axis=-1)
     A = -jnp.exp(p["A_log"])                                     # (nh,)
     xh = constrain(xh, "dp", None, "tp")
-    xhh = xh.reshape(B, S, nh, hp)
+    xhh = xh.reshape(B, S, G, Hg, hp)
     if h0 is None:
         h0 = jnp.zeros((B, nh, hp, N), jnp.float32)
-    y, hT = _ssd_scan(xhh, dt, A, Bc, Cc, h0, cfg.ssm_chunk)
-    y = y + p["ssm_D"][:, None] * xhh.astype(jnp.float32)
-    y = y.reshape(B, S, dI).astype(cdt)
-    y = rms_norm(y * jax.nn.silu(z), p["ssm_norm"], cfg.rms_eps)
+    y, hT = _ssd_scan(xhh, dt.reshape(B, S, G, Hg), A.reshape(G, Hg),
+                      Bc.reshape(B, S, G, N), Cc.reshape(B, S, G, N),
+                      h0.reshape(B, G, Hg, hp, N), cfg.ssm_chunk)
+    y = y + p["ssm_D"].reshape(G, Hg)[:, :, None] * xhh.astype(jnp.float32)
+    y = _gated_norm(p, y.reshape(B, S, dI).astype(cdt), z, cfg)
     out = y @ p["out_proj"].astype(cdt)
-    out = constrain(out, "dp", None, None)
+    return constrain(out, "dp", None, None), hT.reshape(B, nh, hp, N)
+
+
+def _m2_forward(p, x, cfg: ModelConfig, h0=None, return_state=False):
+    dI = cfg.d_inner
+    cdt = dtype_of(cfg.compute_dtype)
+    z, xbc, dt = _m2_split(p, x, cfg)
+    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"].astype(cdt),
+                                   p["conv_b"].astype(cdt)))
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,nh)
+    out, hT = _m2_mix(p, z, xbc, dt, cfg, h0)
     if return_state:
         tail = (x[:, -(cfg.ssm_conv - 1):, :] @ p["in_proj"].astype(cdt)
-                )[..., dI:2 * dI + 2 * N]
+                )[..., dI:dI + cfg.ssm_conv_dim]
         return out, (tail, hT)
     return out
+
+
+def mamba_window(p, x, cfg: ModelConfig, state, valid):
+    """Mamba-2 over a prefill WINDOW that continues a carried state.
+
+    x: (B,S,D); state = (conv (B,K-1,conv_dim) — the K-1 inputs of the conv
+    before the window, h (B,nh,hp,N) f32); valid: (B,S) bool, a per-row
+    prefix (the rest of the window is padding). The conv reads the carried
+    window instead of zeros, padded positions take ``dt = 0`` (no decay, no
+    input: the state passes them unchanged), and the returned conv window
+    ends at each row's last valid token. A row with no valid token keeps
+    its state exactly. Returns (out (B,S,D), (conv, h))."""
+    conv0, h0 = state
+    K = cfg.ssm_conv
+    cdt = dtype_of(cfg.compute_dtype)
+    z, xbc, dt = _m2_split(p, x, cfg)
+    prefix = conv0.astype(xbc.dtype)
+    xc = jax.nn.silu(_causal_conv(xbc, p["conv_w"].astype(cdt),
+                                  p["conv_b"].astype(cdt), prefix))
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    out, hT = _m2_mix(p, z, xc, dt, cfg, h0)
+    n = jnp.sum(valid, axis=1).astype(jnp.int32)                 # (B,)
+    seq = jnp.concatenate([prefix, xbc], axis=1)              # (B,K-1+S,C)
+    idx = n[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+    conv = jnp.take_along_axis(seq, idx[..., None], axis=1)
+    live = n > 0
+    hT = jnp.where(live[:, None, None, None], hT, h0)
+    return out, (conv.astype(conv0.dtype), hT)
 
 
 def _m2_step(p, x1, cfg: ModelConfig, state):
     conv_buf, h = state
     B = x1.shape[0]
     dI, N, nh, hp = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_groups
+    Hg = nh // G
     cdt = dtype_of(cfg.compute_dtype)
-    zxbcdt = x1 @ p["in_proj"].astype(cdt)
-    z, xh, Bc, Cc, dt = jnp.split(
-        zxbcdt, [dI, 2 * dI, 2 * dI + N, 2 * dI + 2 * N], axis=-1)
-    xbc = jnp.concatenate([xh, Bc, Cc], axis=-1)
+    z, xbc, dt = _m2_split(p, x1, cfg)
     xbc, conv_buf = _conv_step(conv_buf.astype(cdt), xbc,
                                p["conv_w"].astype(cdt),
                                p["conv_b"].astype(cdt))
     xbc = jax.nn.silu(xbc)
-    xh, Bc, Cc = jnp.split(xbc, [dI, dI + N], axis=-1)
+    xh, Bc, Cc = jnp.split(xbc, [dI, dI + G * N], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,nh)
     A = -jnp.exp(p["A_log"])
-    a = jnp.exp(dt * A)                                          # (B,nh)
-    xhh = xh.reshape(B, nh, hp).astype(jnp.float32)
-    h = a[..., None, None] * h \
-        + (dt[..., None] * xhh)[..., None] \
-        * Bc.astype(jnp.float32)[:, None, None, :]
-    y = jnp.einsum("bhpn,bn->bhp", h, Cc.astype(jnp.float32)) \
-        + p["ssm_D"][:, None] * xhh
-    y = y.reshape(B, dI).astype(cdt)
-    y = rms_norm(y * jax.nn.silu(z), p["ssm_norm"], cfg.rms_eps)
-    return y @ p["out_proj"].astype(cdt), (conv_buf, h)
+    a = jnp.exp(dt * A).reshape(B, G, Hg)
+    xhh = xh.reshape(B, G, Hg, hp).astype(jnp.float32)
+    Bg = Bc.astype(jnp.float32).reshape(B, G, 1, 1, N)
+    Cg = Cc.astype(jnp.float32).reshape(B, G, N)
+    hg = a[..., None, None] * h.reshape(B, G, Hg, hp, N) \
+        + (dt.reshape(B, G, Hg)[..., None] * xhh)[..., None] * Bg
+    y = jnp.einsum("bghpn,bgn->bghp", hg, Cg) \
+        + p["ssm_D"].reshape(G, Hg)[:, :, None] * xhh
+    y = _gated_norm(p, y.reshape(B, dI).astype(cdt), z, cfg)
+    return y @ p["out_proj"].astype(cdt), (conv_buf, hg.reshape(h.shape))
 
 
 # ==================================================================== dispatch
@@ -314,7 +385,6 @@ def init_mamba_state(cfg: ModelConfig, batch: int, dtype=jnp.float32):
     if cfg.ssm_version == 1:
         return (jnp.zeros((batch, K - 1, cfg.d_inner), dtype),
                 jnp.zeros((batch, cfg.d_inner, cfg.ssm_state), jnp.float32))
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
-    return (jnp.zeros((batch, K - 1, conv_dim), dtype),
+    return (jnp.zeros((batch, K - 1, cfg.ssm_conv_dim), dtype),
             jnp.zeros((batch, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state), jnp.float32))

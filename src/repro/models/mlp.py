@@ -1,4 +1,5 @@
-"""Feed-forward layers: SwiGLU (gated) and plain-GELU variants."""
+"""Feed-forward layers: SwiGLU (gated) and ungated GELU or squared-ReLU
+(``relu(x @ wi) ** 2``, nemotron-h) variants."""
 from __future__ import annotations
 
 from typing import Dict
@@ -33,11 +34,15 @@ def mlp(p, x, cfg: ModelConfig, prefix: str = "") -> jnp.ndarray:
     cdt = dtype_of(cfg.compute_dtype)
     wi = p[prefix + "wi"].astype(cdt)
     wd = p[prefix + "wd"].astype(cdt)
-    if cfg.mlp_gated:
+    if cfg.mlp_act == "swiglu":
         wg = p[prefix + "wg"].astype(cdt)
         h = jax.nn.silu(x @ wg) * (x @ wi)
-    else:
+    elif cfg.mlp_act == "relu2":
+        h = jnp.square(jax.nn.relu(x @ wi))
+    elif cfg.mlp_act == "gelu":
         h = jax.nn.gelu(x @ wi)
+    else:
+        raise ValueError(f"unknown mlp_act {cfg.mlp_act!r}")
     h = constrain(h, "dp", None, "tp")
     if h.shape[-1] != wd.shape[0]:     # serve TP: concat local d_ff columns
         h = gather_tp(h, -1)

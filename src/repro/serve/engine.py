@@ -104,6 +104,19 @@ retirement. The old per-call grouped fallback is retired from
 ``submit()``/``generate()`` and survives only as the benchmark baseline
 (:meth:`ServeEngine._generate_grouped`).
 
+A layer-pattern model (nemotron-h: Mamba-2, MLP and attention layers) is
+served by the paged engine with two kinds of state from one manager: the
+paged pool for its attention layers, and beside it one ``(conv, h)`` slot
+per batch row for its Mamba layers (:func:`repro.serve.kvcache
+.init_recurrent_state`), handed with the pool to every compiled paged
+program. Every prompt window, window 0 included, runs through
+:func:`repro.models.lm.prefill_window_paged` in the decode stage and
+carries the row's state; a row's slot is zeroed when it is seated
+(``serve.state_seat``), and a decoding row preempted by the synchronous
+loop saves its state and live KV pages to the host (``serve.state_save``)
+and resumes from them on fresh blocks. Its prefix cache is refused: a
+cached block would need the state at its boundary.
+
 Client API: :meth:`submit` returns a :class:`ServeRequest` future;
 :meth:`ServeRequest.result` blocks for the tokens. :meth:`generate` remains
 as a thin compatibility shim over submit/result (greedy tokens bit-identical
@@ -149,7 +162,9 @@ from .faultinject import FaultInjected, FaultInjector
 from .journal import Journal, replay as replay_journal
 from .kvcache import (SINK_BLOCK, BlockPool, copy_blocks,
                       extend_block_tables, init_kv_pool,
-                      scatter_prefill_rows, set_carry_rows, set_table_rows)
+                      init_recurrent_state, scatter_prefill_rows,
+                      set_carry_rows, set_state_rows, set_table_rows,
+                      write_blocks)
 from .prefix import PrefixCache
 from .scheduler import Scheduler, ServeRequest
 from .snapshot import corrupt_snapshot, read_snapshot, write_snapshot
@@ -174,12 +189,13 @@ def _env_mesh_ctx(cfg: ModelConfig) -> Optional[ShardCtx]:
 
     SSM/hybrid configs have no KV heads to partition, so the env path
     leaves them on a single device (their slot state is replicated by
-    construction anyway)."""
+    construction anyway); so does a layer pattern, which serves on one
+    device."""
     env = os.environ.get("REPRO_MESH_MODEL", "").strip()
     if not env:
         return None
     mp = min(int(env), jax.device_count())
-    if cfg.ssm or cfg.hybrid_attn_every:
+    if cfg.ssm or cfg.hybrid_attn_every or cfg.layer_pattern:
         return None
     while mp > 1 and not serve_attn_sharded(cfg, mp):
         mp -= 1
@@ -215,7 +231,8 @@ class ServeEngine:
         prefills across multiple pipeline cycles (window 0 in the prefill
         stage, the rest streamed by the decode stage) while resident rows
         keep decoding. Defaults to ``decode_chunk * block_size``. Paged
-        (attention) archs only; SSM/hybrid prompts prefill whole.
+        archs only (attention, and layer patterns, whose every window
+        carries the recurrent state); SSM/zamba2 prompts prefill whole.
     max_batch:
         decode slot count; the compiled chunk program always runs this many
         rows (inactive rows are masked), so batch composition changes never
@@ -253,7 +270,8 @@ class ServeEngine:
         resident row is preempted (see ``docs/prefix_caching.md``). None
         resolves via the ``REPRO_PREFIX_CACHE`` env var (default off —
         the uncached path is the bit-exact reference). Paged
-        (attention) archs only; ignored for SSM/hybrid models.
+        (attention) archs only; ignored for SSM/hybrid models, and refused
+        (``ValueError``) for a layer pattern with Mamba layers.
     tier_targets:
         per-priority-tier guaranteed minimum share of each admission
         cycle (``{tier: share}``, see :class:`repro.serve.scheduler
@@ -330,6 +348,9 @@ class ServeEngine:
         #: model-axis (tensor-parallel) size of the serve mesh; 1 = the
         #: single-device reference engine
         self._tp = serve_tp_size(self.ctx)
+        if self._tp > 1 and cfg.layer_pattern:
+            raise ValueError(f"{cfg.name}: a layer-pattern model serves on "
+                             f"one device, not a model={self._tp} mesh")
         if self._tp > 1:
             # an explicit indivisible mesh is a typed error, not a clamp
             validate_serve_mesh(cfg, self._tp)
@@ -359,6 +380,9 @@ class ServeEngine:
         #: continuous batching pages the attention KV cache; SSM/hybrid
         #: recurrent state is O(1)/seq and lives in a fixed-slot state pool
         self.paged = not (cfg.ssm or cfg.hybrid_attn_every)
+        #: a layer pattern with Mamba layers: per-row recurrent state beside
+        #: the paged pool, carried by every prefill window and decode step
+        self._recurrent = self.paged and cfg.mamba_layers > 0
         from ..kernels.ops import PAGED_IMPLS, default_paged_impl
         if paged_impl is not None and paged_impl not in PAGED_IMPLS:
             raise ValueError(f"paged_impl={paged_impl!r}: expected one of "
@@ -375,6 +399,12 @@ class ServeEngine:
         if prefix_cache is None:
             prefix_cache = os.environ.get("REPRO_PREFIX_CACHE", "") \
                 .strip().lower() in ("1", "true", "yes", "on")
+        if prefix_cache and self._recurrent:
+            raise ValueError(
+                f"{cfg.name}: the prefix cache is refused for a model with "
+                f"recurrent (Mamba) layers: a cached prefix block would also "
+                f"need the recurrent state at its boundary, which is not "
+                f"kept")
         #: cross-request KV block sharing (paged archs only); False = the
         #: uncached bit-exact reference path
         self.prefix_cache = bool(prefix_cache) and self.paged
@@ -473,7 +503,7 @@ class ServeEngine:
                       "cancelled": 0, "watchdog_fires": 0,
                       "row_failures": 0, "recovered": 0,
                       "replayed_tokens": 0, "drain_preempted": 0,
-                      "warm_started": 0}
+                      "warm_started": 0, "recurrent_windows": 0}
 
         self._prefix: Optional[PrefixCache] = None
         self._kv_geom = (kv_blocks, block_size)   # failure-isolation reinit
@@ -545,6 +575,16 @@ class ServeEngine:
                                            donate_argnums=(1,))
             self._extend_tables = jax.jit(extend_block_tables)
             self._set_rows = jax.jit(set_table_rows)
+            self._sstate = None
+            if self._recurrent:
+                # the recurrent state's one slot per row, in the
+                # ``{"ssm": (conv, h)}`` layout the slot pool's checkpoint
+                # code reads (_save_slot_state)
+                self._sstate = {"ssm": init_recurrent_state(cfg, B)}
+                self._seat_state = jax.jit(set_state_rows,
+                                           donate_argnums=(0,))
+                self._write_blocks = jax.jit(write_blocks,
+                                             donate_argnums=(0,))
         else:
             self._max_seq = max_seq_len or 512
             self.prefill_chunk = None
@@ -630,7 +670,11 @@ class ServeEngine:
             "row_failed": metrics.counter("serve.row_failures"),
             "recovered": metrics.counter("serve.recovered"),
             "replayed": metrics.counter("serve.replayed_tokens"),
+            "recurrent_windows": metrics.counter("serve.recurrent_windows"),
         }
+        if self._recurrent:
+            metrics.gauge("serve.recurrent_state_bytes").set(
+                sum(a.nbytes for a in self._sstate["ssm"]))
 
     def set_journal(self, journal) -> None:
         """Attach (or detach, with None) a request :class:`~repro.serve
@@ -730,6 +774,20 @@ class ServeEngine:
         elif self._repl_ns is not None:
             pkv = jax.device_put(pkv, self._repl_ns)
         return pkv
+
+    def _device_cache(self):
+        """What the compiled paged programs read and donate: the KV pool,
+        paired with a layer pattern's recurrent state."""
+        if self._recurrent:
+            return (self._pkv, self._sstate["ssm"])
+        return self._pkv
+
+    def _take_device_cache(self, cache) -> None:
+        """Keep what a paged program returned (see :meth:`_device_cache`)."""
+        if self._recurrent:
+            self._pkv, self._sstate["ssm"] = cache
+        else:
+            self._pkv = cache
 
     # ---------------------------------------------------------- compiled fns
     def _prefill_impl(self, params, tokens, last_positions, max_len: int):
@@ -1169,7 +1227,7 @@ class ServeEngine:
                 budget = self._pool.num_free_unreserved + px.num_parked
             else:
                 def need_for(r):
-                    return self._pool.blocks_for(r.prompt_len)
+                    return self._pool.blocks_for(self._seat_tokens(r))
                 budget = self._pool.num_free_unreserved
             popped = self._scheduler.try_admit(free_slots, budget, need_for,
                                                hopeless=self._hopeless_why)
@@ -1178,7 +1236,7 @@ class ServeEngine:
                 # matched block) and allocate only the uncached suffixes
                 hits = [px.match_and_pin(r.prompt) if px is not None
                         else None for r in popped]
-                needs = [self._pool.blocks_for(r.prompt_len)
+                needs = [self._pool.blocks_for(self._seat_tokens(r))
                          - (len(h.blocks) if h is not None else 0)
                          for r, h in zip(popped, hits)]
                 if self._fi is not None and self._fi.fire("alloc_fail"):
@@ -1286,6 +1344,12 @@ class ServeEngine:
         self._log("pump", pf.token, None)
         return ("pump", None)
 
+    @staticmethod
+    def _seat_tokens(r: ServeRequest) -> int:
+        """Tokens of KV a request needs when seated: its prompt, or the
+        saved length of a checkpoint-preempted layer-pattern row."""
+        return r.prompt_len if r._ssm_ckpt is None else r._ssm_ckpt[1]
+
     def _st_prefill(self, pf, msg):
         kind, payload = msg
         if kind != "admit":
@@ -1380,7 +1444,10 @@ class ServeEngine:
         miss = [g for g in group if g[2] is None or g[2].tokens == 0]
         hitg = [g for g in group if not (g[2] is None or g[2].tokens == 0)]
         group = miss + hitg
-        if not miss:
+        if not miss or self._recurrent:
+            # a layer pattern's recurrent state starts at the decode
+            # stage's seat: every one of its prompt windows, window 0
+            # included, streams there and carries the state
             self._log("prefill", pf.token, [r.id for r in reqs])
             return ("admit", (group, 0, None, None, None, 0))
         longest = max(g[0].prompt_len for g in miss)
@@ -1461,6 +1528,7 @@ class ServeEngine:
         c_len, c_last, c_rem = [], [], []
         fork_src, fork_dst = [], []
         reg_slots = []
+        fresh, restore = [], []      # recurrent slots to zero / to restore
         for i, (req, blocks, hit) in enumerate(group):
             shared = list(hit.blocks) if (hit is not None and i >= n_miss) \
                 else []
@@ -1498,7 +1566,22 @@ class ServeEngine:
             else:
                 self._pref_pos[slot] = min(req.prompt_len, C0)
             self._lengths[slot] = self._pref_pos[slot]
-            if i < n_miss and req.prompt_len <= C0:
+            ckpt = req._ssm_ckpt if self._recurrent else None
+            if ckpt is not None:
+                # checkpoint-preempted layer-pattern row: its saved state
+                # and KV pages go back in, and it decodes on from where it
+                # stopped — no prefill, no token emitted again
+                st, length, last, rem, out = ckpt
+                req._ssm_ckpt = None
+                restore.append((slot, st, tab))
+                self._pref_pos[slot] = req.prompt_len
+                self._lengths[slot] = length
+                self._slot_phase[slot] = "decode"
+                self._last[slot] = last
+                self._rem[slot] = rem
+                self._slot_out[slot] = list(out)
+                req.state = "decoding"
+            elif i < n_miss and req.prompt_len <= C0:
                 self._slot_phase[slot] = "decode"
                 self._last[slot] = first[i]
                 self._rem[slot] = req.max_new - 1
@@ -1510,6 +1593,7 @@ class ServeEngine:
                 self._slot_phase[slot] = "prefill"
                 self._last[slot] = 0
                 self._rem[slot] = 0   # masked out of decode until prefilled
+                fresh.append(slot)
             if self._tr is not None:
                 self._note_seated(slot, req, now)
             rows_idx.append(slot)
@@ -1527,6 +1611,8 @@ class ServeEngine:
         self._tables_dev = self._set_rows(
             self._tables_dev, jnp.asarray(rows_idx, jnp.int32),
             jnp.asarray(np.stack(rows_tab)))
+        if self._recurrent:
+            self._seat_recurrent(fresh, restore)
         if self.async_decode:
             # admission scatter onto the device carry, sequenced BEFORE the
             # next chunk dispatch: the seated rows were inactive (rem==0) in
@@ -1557,6 +1643,30 @@ class ServeEngine:
         with self._state_lock:
             self._premerge.pop(pf.token, None)   # fully seated
         self._note_resident()
+
+    def _seat_recurrent(self, fresh: List[int], restore: List[tuple]) -> None:
+        """Seat a group's recurrent slots: zero each fresh row's (nothing of
+        the slot's last tenant may leak into its first window), and put a
+        checkpointed row's saved state and KV pages back (the pages into
+        the row's new blocks). One padded launch each, so the shapes
+        compile once per engine."""
+        with span("serve.state_seat", self._tr,
+                  rows=len(fresh) + len(restore)):
+            if fresh:
+                A = self._scheduler.max_admit
+                rows = fresh + [fresh[-1]] * (A - len(fresh))
+                self._sstate["ssm"] = self._seat_state(
+                    self._sstate["ssm"], jnp.asarray(rows, jnp.int32))
+            for slot, st, tab in restore:
+                self._restore_slot_state(slot, st)
+                pages = st["pages"]
+                mb = self._tables.shape[1]
+                ids = np.full((mb,), SINK_BLOCK, np.int32)
+                ids[:len(pages)] = tab[:len(pages)]
+                full = np.zeros((mb,) + pages.shape[1:], pages.dtype)
+                full[:len(pages)] = pages
+                self._pkv = self._write_blocks(self._pkv, jnp.asarray(ids),
+                                               jnp.asarray(full))
 
     def _copy_blocks_padded(self, srcs: List[int], dsts: List[int]) -> None:
         """One :func:`repro.serve.kvcache.copy_blocks` launch, padded with
@@ -1676,6 +1786,12 @@ class ServeEngine:
     def _restore_slot_state(self, slot: int, st: Dict[str, Any]) -> None:
         """Scatter a :meth:`_save_slot_state` checkpoint back into a
         (possibly different) slot of the fixed-slot state pool."""
+        if self._recurrent:
+            conv, h = st["ssm"]
+            self._sstate["ssm"] = self._seat_state(
+                self._sstate["ssm"], jnp.asarray([slot], jnp.int32),
+                (jnp.asarray(conv)[:, None], jnp.asarray(h)[:, None]))
+            return
         if self.cfg.hybrid_attn_every:
             conv, h = st["g_ssm"]
             sc, sh = self._sstate["g_ssm"]
@@ -1738,13 +1854,17 @@ class ServeEngine:
                 start[b] = s
                 last_idx[b] = min(len(prompt) - 1 - s, C - 1)
                 ks[b] = k
-            first, pkv = self._prefill_window(
-                self.params, self._pkv, self._tables_dev, jnp.asarray(toks),
-                jnp.asarray(start), jnp.asarray(valid),
+            first, cache = self._prefill_window(
+                self.params, self._device_cache(), self._tables_dev,
+                jnp.asarray(toks), jnp.asarray(start), jnp.asarray(valid),
                 jnp.asarray(last_idx))
-        self._pkv = pkv
+        self._take_device_cache(cache)
         with self._state_lock:
             self.stats["prefill_windows"] += 1
+            if self._recurrent:
+                self.stats["recurrent_windows"] += 1
+        if self._recurrent and self._mh is not None:
+            self._mh["recurrent_windows"].inc()
         return {"first": first, "rows": pref, "k": ks, "token": pf.token,
                 "gen": {b: self._slot_gen[b] for b in pref},
                 "t_disp": time.perf_counter()}
@@ -2083,33 +2203,45 @@ class ServeEngine:
         """Zero one vacated seat's device state: its block-table row
         (paged) and its carry row (async). Both scatters are PADDED to
         the admission cap with duplicate rows (idempotent writes) so
-        they reuse the merge's compiled shapes — a 1-row scatter here
-        would JIT-compile on the engine's FIRST preemption/eviction,
-        a ~100ms+ stall landing exactly in the overloaded decode cycle
+        they reuse the merge's compiled shapes, and the zero rows are
+        uploaded from the host as the merge uploads its rows (a
+        ``jnp.zeros`` of the admission cap's shape is a program of its
+        own where the cap differs from ``max_batch``) — anything new here
+        would JIT-compile on the engine's FIRST preemption/eviction, a
+        ~100ms+ stall landing exactly in the overloaded decode cycle
         the preemption was meant to relieve (it showed up as a 10x+
         tier-0 TTFT outlier in ``benchmarks/serve_slo.py``)."""
         A = self._scheduler.max_admit
         if self.paged:
             self._tables_dev = self._set_rows(
                 self._tables_dev, jnp.asarray([slot] * A, jnp.int32),
-                jnp.zeros((A, self._tables.shape[1]), jnp.int32))
+                jnp.asarray(np.zeros((A, self._tables.shape[1]), np.int32)))
         if self.async_decode:
             self._scatter_carry([slot], [0], [0], [0], pad_to=A)
 
     def _preempt(self, slot: int, pf) -> None:
         req = self._slot_req[slot]
-        if not self.paged and not self.async_decode \
+        if (self._recurrent or not self.paged) and not self.async_decode \
                 and self._slot_phase[slot] == "decode":
-            # SSM/hybrid sync mode: recurrent state is O(1)/seq, so a
-            # CHECKPOINT preemption is cheap — capture the slot's exact
-            # state + progress and re-seat it at the next admission with
-            # no prefill replay. Async falls through to plain replay (the
-            # in-flight chunk already advanced the donated state past the
-            # host mirrors, so a capture here would be stale).
-            req._ssm_ckpt = (self._save_slot_state(slot),
-                             int(self._lengths[slot]),
-                             int(self._last[slot]), int(self._rem[slot]),
-                             list(self._slot_out[slot] or []))
+            # recurrent state, sync mode: it is O(1)/seq, so a CHECKPOINT
+            # preemption is cheap — capture the slot's exact state (and a
+            # layer pattern's live KV pages) + progress and re-seat it at
+            # the next admission with no prefill replay. A row mid-prefill,
+            # and async mode, fall through to plain replay (the in-flight
+            # chunk already advanced the donated state past the host
+            # mirrors, so a capture here would be stale).
+            with span("serve.state_save", self._tr, req=req.id):
+                st = self._save_slot_state(slot)
+                if self._recurrent:
+                    n = self._pool.blocks_for(int(self._lengths[slot]))
+                    ids = np.full((self._tables.shape[1],), SINK_BLOCK,
+                                  np.int32)
+                    ids[:n] = self._slot_blocks[slot][:n]
+                    st["pages"] = np.asarray(
+                        jax.device_get(self._pkv[jnp.asarray(ids)]))[:n]
+                req._ssm_ckpt = (st, int(self._lengths[slot]),
+                                 int(self._last[slot]), int(self._rem[slot]),
+                                 list(self._slot_out[slot] or []))
         with self._state_lock:
             self._slot_req[slot] = None
             self._slot_out[slot] = None
@@ -2329,6 +2461,8 @@ class ServeEngine:
             self._tables_dev = self._dev(
                 np.zeros(self._tables.shape, np.int32))
             self._slot_prompt = [None] * B
+            if self._recurrent:
+                self._sstate = {"ssm": init_recurrent_state(self.cfg, B)}
             self._pool.set_metrics(metrics)
             if self.prefix_cache:
                 self._prefix = PrefixCache(self._pool)
@@ -2410,11 +2544,11 @@ class ServeEngine:
         n = self.decode_chunk
         with span("serve.dispatch", tr) as disp:  # carry upload + launch
             if self.paged:
-                pkv, tok, ln, rm, toks = self._decode_paged(
-                    self.params, self._pkv, self._tables_dev,
+                cache, tok, ln, rm, toks = self._decode_paged(
+                    self.params, self._device_cache(), self._tables_dev,
                     jnp.asarray(self._lengths), jnp.asarray(self._last),
                     jnp.asarray(self._rem), n=n)
-                self._pkv = pkv
+                self._take_device_cache(cache)
             else:
                 st, tok, ln, rm, toks = self._decode_slots(
                     self.params, self._sstate, jnp.asarray(self._last),
@@ -2487,10 +2621,10 @@ class ServeEngine:
             rem_before = self._rem.copy()
             with span("serve.dispatch", tr) as disp:
                 if self.paged:
-                    pkv, tok, ln, rm, toks = self._decode_paged(
-                        self.params, self._pkv, self._tables_dev,
+                    cache, tok, ln, rm, toks = self._decode_paged(
+                        self.params, self._device_cache(), self._tables_dev,
                         *self._carry, n=n)
-                    self._pkv = pkv
+                    self._take_device_cache(cache)
                 else:
                     lengths, last, rem = self._carry
                     st, tok, ln, rm, toks = self._decode_slots(

@@ -51,6 +51,12 @@ Two halves, deliberately separated:
   ``gather_pages`` is the *reference oracle* read path: the serve hot path
   reads pages in place via :mod:`repro.kernels.paged_attention` instead of
   materializing a gathered copy.
+
+A layer-pattern model (Mamba-2 beside attention) keeps its second kind of
+state here too: ``init_recurrent_state`` gives one ``(conv, h)`` slot per
+batch row for its Mamba layers (the pool then holds its attention layers
+only), ``set_state_rows`` zeroes or restores rows of it at seating, and
+``write_blocks`` puts a checkpointed row's saved pages back into the pool.
 """
 from __future__ import annotations
 
@@ -405,9 +411,42 @@ def init_kv_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
     from ..models.layers import dtype_of
     cdt = dtype_of(cfg.compute_dtype)
     P = kv_pack(cfg.num_kv_heads // tp, cfg.hd)
-    shape = (num_blocks, cfg.num_layers, 2, cfg.num_kv_heads // P,
+    shape = (num_blocks, cfg.attn_layers, 2, cfg.num_kv_heads // P,
              block_size, P * cfg.hd)
     return jnp.zeros(shape, cdt)
+
+
+def init_recurrent_state(cfg: ModelConfig, batch: int):
+    """The per-row recurrent state of a layer pattern's Mamba layers, kept
+    beside the paged pool by the same engine: ``(conv (Lm, B, K-1,
+    conv_dim)`` in the compute dtype, ``h (Lm, B, heads, head_dim, N)``
+    f32), one slot a batch row, zero for a fresh sequence — the layout of
+    :func:`repro.models.lm.init_cache`'s ``"ssm"``. Its bytes are fixed by
+    ``max_batch``, whatever the sequences' lengths (80 MiB a row at
+    nemotron-h's five Mamba layers of 256 x 64 x 256)."""
+    from ..models.lm import init_cache
+    return init_cache(cfg, batch, 0)["ssm"]
+
+
+def set_state_rows(state, rows: jnp.ndarray, vals=None):
+    """Seat rows of the recurrent state: ``state`` ``(conv, h)`` as
+    :func:`init_recurrent_state`; rows: (M,) int32 (pad with repeats —
+    duplicate writes are idempotent); ``vals`` ``(conv (Lm, M, ...), h
+    (Lm, M, ...))`` to restore, or None to zero the rows (a new
+    admission: nothing of the slot's last tenant may leak)."""
+    if vals is None:
+        return tuple(s.at[:, rows].set(0) for s in state)
+    return tuple(s.at[:, rows].set(v.astype(s.dtype))
+                 for s, v in zip(state, vals))
+
+
+def write_blocks(pool: jnp.ndarray, ids: jnp.ndarray, pages: jnp.ndarray
+                 ) -> jnp.ndarray:
+    """Write saved pages back into the pool: ``pool[ids[i]] = pages[i]``
+    (a checkpoint-preempted row resumed on fresh blocks; pad ``ids`` with
+    the sink). pool: (N, L, 2, R, bs, W); ids: (M,); pages: (M, L, 2, R,
+    bs, W)."""
+    return pool.at[ids].set(pages.astype(pool.dtype))
 
 
 def _pack(x: jnp.ndarray, width: int) -> jnp.ndarray:

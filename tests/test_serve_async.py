@@ -172,6 +172,45 @@ def test_async_tight_pool_stall_yields_to_resident(setup):
     assert _pool_restored(a_eng)
 
 
+def test_first_preemption_after_warmup_compiles_nothing(setup):
+    """Eight rows over an admission cap of four, the default (synchronous)
+    decode loop: once every prompt-window shape has run (one request at a
+    time), the engine's first preemption traces and compiles nothing —
+    vacating a seat reuses the merge's programs, however ``max_batch`` and
+    the admission cap differ."""
+    cfg, params = setup
+    kw = dict(max_batch=8, decode_chunk=4, kv_blocks=40, block_size=4,
+              prefill_chunk=8)
+    events = []
+    counting = threading.Event()
+
+    def on_event(name, secs, **_):
+        if counting.is_set() and name.startswith("/jax/core/compile/"):
+            events.append(name)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    rng = np.random.default_rng(7)
+    with ServeEngine(cfg, params, **kw) as eng:
+        assert eng._scheduler.max_admit < len(eng._slot_req)
+        warm = []
+        for n in (1, 2, 3, 4, 5, 8, 9, 16, 17):
+            r = eng.submit(rng.integers(1, cfg.vocab_size, size=n)
+                           .astype(np.int32), max_new=17)
+            warm.append(r)
+            while r.admitted_at is None and not r.done():
+                time.sleep(0.002)
+        for r in warm:
+            eng.result(r, timeout=240)
+        assert eng.stats["preempted"] == 0
+        counting.set()
+        reqs = [eng.submit(rng.integers(1, cfg.vocab_size, size=16)
+                           .astype(np.int32), max_new=16) for _ in range(8)]
+        for r in reqs:
+            eng.result(r, timeout=240)
+        counting.clear()
+        assert eng.stats["preempted"] >= 1
+    assert events == []
+
+
 # ------------------------------------------------------- deferred-free fence
 def test_blockpool_deferred_free_fence():
     """free_deferred parks blocks behind TWO release_deferred advances;
